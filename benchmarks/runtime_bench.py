@@ -96,6 +96,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.planner import ModelSpec
 from repro.preprocessing.formats import ImageFormat, StoredImage
 from repro.runtime import MemoryConfig, RuntimeConfig, SmolRuntime
@@ -1186,6 +1187,7 @@ def _run_hot_corpus_leg(args) -> dict:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     # defaults make the workload host-decode-bound (big stored images, small
     # model), the regime the paper targets and where worker count matters

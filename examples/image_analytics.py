@@ -20,6 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from benchmarks import vision_common as V
 from repro.core.planner import ModelSpec
 from repro.preprocessing.formats import (
@@ -77,6 +78,7 @@ def build_model_set(ds: str):
 
 
 def main():
+    enable_compile_cache()
     ds = "animals-10"
     stored = V.dataset_cache(ds, 8, 96)[4]
     models, model_fns = build_model_set(ds)

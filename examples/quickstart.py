@@ -9,6 +9,7 @@ over 𝒟 x ℱ, and pipelined execution of the chosen plan.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import dag
 from repro.core.cost_model import estimate_smol
 from repro.core.engine import measure_plan
@@ -19,6 +20,7 @@ from repro.preprocessing.ops import TensorMeta
 
 
 def main():
+    enable_compile_cache()
     # 1. data: one logical dataset, several physical encodings (ℱ)
     stored, labels = datasets.image_dataset("bike-bird", 24, seed=0)
     print(f"dataset: {len(stored)} images, formats {[f.key for f in stored[0].formats()]}")

@@ -9,6 +9,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.pipeline import PrefetchIterator, ShardedBatchSource, synthetic_lm_batch_fn
 from repro.models.config import ModelConfig
 from repro.serving import tokenizer as tok
@@ -18,6 +19,7 @@ from repro.training.train_loop import TrainConfig, train
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--requests", type=int, default=6)

@@ -13,6 +13,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import aggregation
 from repro.data import datasets
 
@@ -24,6 +25,7 @@ def specialized_counts(frames: np.ndarray) -> np.ndarray:
 
 
 def main():
+    enable_compile_cache()
     stored, counts = datasets.video_dataset("amsterdam", num_frames=120, size=64)
     fmts = stored.formats()
     full_fmt, low_fmt = fmts[0], fmts[1]

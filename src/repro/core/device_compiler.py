@@ -46,6 +46,7 @@ import numpy as np
 from repro.core import dag as dag_mod
 from repro.kernels.fused_preproc.ops import bilinear_matrix, fused_resize_affine
 from repro.kernels.idct.ops import dequant_idct
+from repro.kernels.mode import resolve_interpret
 from repro.preprocessing import ops as P
 from repro.preprocessing.ops import PreprocOp, TensorMeta
 
@@ -59,11 +60,6 @@ def resolve_impl(impl: str = "auto") -> str:
     if env in ("pallas", "jnp"):
         return env
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPU."""
-    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------- dispatch calibration
@@ -453,6 +449,9 @@ class DevicePreprocProgram:
     # the staged batch size this program was compiled for (a ProgramSet
     # holds one program per bucketed size)
     batch_size: int = 0
+    # True when the program's Pallas kernels run in interpret mode (part of
+    # the cache key; False on a TPU)
+    interpret: bool = False
     # invoked as listener(program, first_dispatch_seconds) when dispatch #1
     # pays the jit trace + XLA compile — the facade counts post-warmup
     # compiles and emits "compile" telemetry spans through it
@@ -475,15 +474,18 @@ class DevicePreprocProgram:
         return 1  # the whole suffix + DNN is one XLA program
 
     def __call__(self, batch):
-        self.dispatch_count += 1
-        if self.dispatch_count == 1:
+        if self.dispatch_count == 0:
             t0 = time.perf_counter()
             out = self.fn(_place(batch, self.device))
             jax.block_until_ready(out)
             self.first_dispatch_seconds = time.perf_counter() - t0
+            # counted only once it ran: a compile that raised leaves the
+            # program unready, so a ProgramSet never serves it
+            self.dispatch_count = 1
             if self.compile_listener is not None:
                 self.compile_listener(self, self.first_dispatch_seconds)
             return out
+        self.dispatch_count += 1
         return self.fn(_place(batch, self.device))
 
     def lower(self, batch):
@@ -688,8 +690,7 @@ def compile_device_program(
     if backend not in ("fused", "reference"):
         raise ValueError(f"device_backend must be 'fused' or 'reference', got {backend!r}")
     impl = resolve_impl(impl) if backend == "fused" else "chain"
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     key = program_cache_key(
         device_ops, in_meta, batch_size, backend, impl, model_key, interpret, donate,
         device,
@@ -725,6 +726,7 @@ def compile_device_program(
         out_meta=out_meta,
         device=device,
         batch_size=batch_size,
+        interpret=interpret,
         build_seconds=time.perf_counter() - t_build,
     )
     if cache is not None:
@@ -779,8 +781,7 @@ def compile_coeff_program(
         raise ValueError(f"scaled-IDCT factor must be 1, 2 or 4, got {factor}")
     if layout not in ("padded", "packed"):
         raise ValueError(f"layout must be 'padded' or 'packed', got {layout!r}")
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     impl = resolve_impl(impl)
     n_br, n_bc = header.n_br, header.n_bc
     cbr, cbc = jpeg_mod.chroma_grid(header)
@@ -856,7 +857,14 @@ def compile_coeff_program(
         if subsample:  # 2x2 nearest upsample back to the (scaled) luma grid
             c = jnp.repeat(jnp.repeat(c, 2, axis=2), 2, axis=3)
         ycc = jnp.concatenate([y[:, None, :hs, :ws], c[:, :, :hs, :ws]], axis=1) + 128.0
-        rgb = jnp.einsum("rc,nchw->nrhw", rgb_mat, ycc - jnp.asarray([0.0, 128.0, 128.0])[:, None, None])
+        # HIGHEST: a bf16 pass (the TPU's f32 default) moves the color
+        # conversion by up to a pixel level before the round below
+        rgb = jnp.einsum(
+            "rc,nchw->nrhw",
+            rgb_mat,
+            ycc - jnp.asarray([0.0, 128.0, 128.0])[:, None, None],
+            precision=jax.lax.Precision.HIGHEST,
+        )
         rgb = jnp.clip(jnp.round(rgb), 0.0, 255.0)  # the decoded uint8 pixel grid
         return model_fn(preproc(rgb))
 
@@ -877,6 +885,7 @@ def compile_coeff_program(
         coeff_layout=layout,
         device=device,
         batch_size=batch_size,
+        interpret=interpret,
         build_seconds=time.perf_counter() - t_build,
     )
     if cache is not None:

@@ -46,10 +46,7 @@ def ring_allreduce(x: jnp.ndarray, axis_name: str, chunks: int | None = None) ->
     1/P-sized chunks — the canonical bandwidth-optimal schedule, and a
     form XLA can overlap with compute chunk-by-chunk.
     """
-    if hasattr(jax.lax, "axis_size"):
-        p = jax.lax.axis_size(axis_name)
-    else:  # older jax: derive the axis size collectively
-        p = jax.lax.psum(1, axis_name)
+    p = jax.lax.axis_size(axis_name)
     if p == 1:
         return x
     me = jax.lax.axis_index(axis_name)

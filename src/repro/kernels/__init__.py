@@ -5,8 +5,10 @@ Each kernel package ships three files:
   ops.py    — the jit'd public wrapper (handles padding, grids, dtypes),
   ref.py    — a pure-jnp oracle used by the allclose test sweeps.
 
-Kernels target TPU (MXU-aligned tiles); on this CPU-only container they are
-validated with ``interpret=True``.
+Kernels target TPU (MXU-aligned tiles).  The wrappers take
+``interpret=None``: compiled on a TPU, interpreted on any other backend
+(``kernels/mode.py``), which is how the CPU test suite runs them.
+``tests/test_tpu_compile.py`` compiles them for a described v5e.
 
 * idct            — fused dequantize + 8x8 inverse DCT over macroblock grids
                     (the device half of SMOL's split JPEG decode)

@@ -8,6 +8,7 @@ from repro.kernels.decode_attention.decode_attention import (
     DEFAULT_BK,
     decode_attention_packed,
 )
+from repro.kernels.mode import resolve_interpret
 
 
 def decode_attention(
@@ -18,7 +19,7 @@ def decode_attention(
     window: int | None = None,
     scale: float | None = None,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,  # CPU container default; False on real TPU
+    interpret: bool | None = None,  # None: compiled on TPU, interpreted elsewhere
 ) -> jnp.ndarray:
     """Single-token decode attention over a (padded) KV cache."""
     b, h, d = q.shape
@@ -41,6 +42,6 @@ def decode_attention(
     vf = v.reshape(b * kvh, sp, d)
     lens = jnp.repeat(lengths.astype(jnp.int32), kvh).reshape(b * kvh, 1)
     out = decode_attention_packed(
-        qp, kf, vf, lens, scale=scale, window=window, bk=bk_eff, interpret=interpret
+        qp, kf, vf, lens, scale=scale, window=window, bk=bk_eff, interpret=resolve_interpret(interpret)
     )
     return out.reshape(b, kvh, group, d).reshape(b, h, d)

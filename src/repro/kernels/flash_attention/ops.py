@@ -9,6 +9,7 @@ from repro.kernels.flash_attention.flash_attention import (
     DEFAULT_BQ,
     flash_attention_bhsd,
 )
+from repro.kernels.mode import resolve_interpret
 
 
 def flash_attention(
@@ -20,7 +21,7 @@ def flash_attention(
     scale: float | None = None,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,  # CPU container default; False on real TPU
+    interpret: bool | None = None,  # None: compiled on TPU, interpreted elsewhere
 ) -> jnp.ndarray:
     """Flash attention over (B, H, S, D) with GQA (KVH | H) and optional
     sliding window.  Pads S up to the block size and crops back."""
@@ -55,7 +56,7 @@ def flash_attention(
         seq_len=s,
         bq=bq_eff,
         bk=bk_eff,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     out = out.reshape(b, h, sp, d)
     return out[:, :, :s, :]

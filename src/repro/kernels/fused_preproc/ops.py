@@ -11,6 +11,7 @@ from repro.kernels.fused_preproc.fused_preproc import (
     DEFAULT_TILE_OH,
     fused_resize_normalize_planar,
 )
+from repro.kernels.mode import resolve_interpret
 from repro.preprocessing.ops import bilinear_coords
 
 
@@ -29,9 +30,6 @@ def bilinear_matrix(in_dim: int, out_dim: int) -> np.ndarray:
     return mat
 
 
-_interp_matrix = bilinear_matrix  # back-compat alias
-
-
 def fused_resize_affine(
     x: jnp.ndarray,  # (B, H, W) float32 planes (B = batch*channels)
     ry: np.ndarray,  # (OH, H) row interpolation matrix (may be crop-sliced)
@@ -40,7 +38,7 @@ def fused_resize_affine(
     bias: jnp.ndarray,  # (B,) per-plane folded offset
     round_uint8: bool = False,
     tile_oh: int = DEFAULT_TILE_OH,
-    interpret: bool = True,  # CPU container default; False on real TPU
+    interpret: bool | None = None,  # None: compiled on TPU, interpreted elsewhere
 ) -> jnp.ndarray:
     """Raw-matrix kernel entry for the device compiler: resize every plane
     through precomputed (possibly crop-sliced) interpolation matrices and
@@ -58,10 +56,10 @@ def fused_resize_affine(
         x,
         jnp.asarray(ry),
         jnp.asarray(rxt),
-        jnp.reshape(jnp.asarray(scale, jnp.float32), (1, b)),
-        jnp.reshape(jnp.asarray(bias, jnp.float32), (1, b)),
+        jnp.reshape(jnp.asarray(scale, jnp.float32), (b,)),
+        jnp.reshape(jnp.asarray(bias, jnp.float32), (b,)),
         tile_oh=tile,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         round_uint8=round_uint8,
     )
     return out[:, :oh, :]
@@ -74,24 +72,18 @@ def fused_resize_normalize(
     scale: np.ndarray,  # (C,) folded multiplier (e.g. 1/255/std)
     bias: np.ndarray,  # (C,) folded offset (e.g. -mean/std)
     tile_oh: int = DEFAULT_TILE_OH,
-    interpret: bool = True,  # CPU container default; False on real TPU
+    interpret: bool | None = None,  # None: compiled on TPU, interpreted elsewhere
 ) -> jnp.ndarray:
     """Resize (C,H,W) -> (C,out_h,out_w) bilinearly and apply per-channel
     affine, all in one fused VMEM pass."""
     x = jnp.asarray(x, dtype=jnp.float32)
-    c, h, w = x.shape
-    tile_oh = min(tile_oh, max(8, 1 << (out_h - 1).bit_length()))
-    oh_pad = -(-out_h // tile_oh) * tile_oh
-    ry = np.zeros((oh_pad, h), dtype=np.float32)
-    ry[:out_h] = _interp_matrix(h, out_h)
-    rxt = np.ascontiguousarray(_interp_matrix(w, out_w).T)
-    out = fused_resize_normalize_planar(
+    _, h, w = x.shape
+    return fused_resize_affine(
         x,
-        jnp.asarray(ry),
-        jnp.asarray(rxt),
-        jnp.asarray(scale, dtype=jnp.float32).reshape(1, c),
-        jnp.asarray(bias, dtype=jnp.float32).reshape(1, c),
+        bilinear_matrix(h, out_h),
+        np.ascontiguousarray(bilinear_matrix(w, out_w).T),
+        scale,
+        bias,
         tile_oh=tile_oh,
         interpret=interpret,
     )
-    return out[:, :out_h, :]

@@ -32,8 +32,13 @@ DEFAULT_TILE = 512
 
 def _idct_kernel(x_ref, m_ref, o_ref):
     # x_ref: (TILE, 64) f32 coeffs; m_ref: (64, 64) fused dequant+IDCT matrix.
+    # HIGHEST: the TPU's default f32 matmul rounds the coefficients and the
+    # dequantized basis to bf16, off by several pixel levels
     o_ref[...] = jnp.dot(
-        x_ref[...], m_ref[...], preferred_element_type=jnp.float32
+        x_ref[...],
+        m_ref[...],
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.idct.idct import DEFAULT_TILE, dequant_idct_tiles
+from repro.kernels.mode import resolve_interpret
 from repro.preprocessing import dct as dct_np
 
 SCALED_POINTS = (8, 4, 2, 1)  # supported IDCT sizes (8 = full resolution)
@@ -63,7 +64,7 @@ def dequant_idct(
     qtable: np.ndarray,  # (8, 8) int quantization table
     point: int = 8,  # IDCT size: 8 full, 4 half-res, 2 quarter-res
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,  # CPU container default; False on real TPU
+    interpret: bool | None = None,  # None: compiled on TPU, interpreted elsewhere
 ) -> jnp.ndarray:
     """Dequantize + 2-D (scaled) IDCT a stack of 8x8 coefficient blocks.
     Returns (N, point, point) f32 (level-shifted pixels; caller adds 128)."""
@@ -75,5 +76,7 @@ def dequant_idct(
     m2q_t = jnp.asarray(
         _m2q_t(np.ascontiguousarray(qtable, dtype=np.int32).tobytes(), point)
     )
-    out = dequant_idct_tiles(flat, m2q_t, tile=tile, interpret=interpret)
+    out = dequant_idct_tiles(
+        flat, m2q_t, tile=tile, interpret=resolve_interpret(interpret)
+    )
     return out[:n, : point * point].reshape(n, point, point)

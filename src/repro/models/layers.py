@@ -14,22 +14,6 @@ import jax.numpy as jnp
 from repro.distributed.sharding import shard
 
 
-def _abstract_mesh():
-    """jax.sharding.get_abstract_mesh across jax versions (None = no mesh)."""
-    try:
-        get = jax.sharding.get_abstract_mesh
-    except AttributeError:
-        try:
-            from jax._src.mesh import get_abstract_mesh as get
-        except ImportError:
-            return None
-    try:
-        mesh = get()
-    except Exception:  # noqa: BLE001 — any failure means "no usable mesh"
-        return None
-    return mesh if hasattr(mesh, "empty") else None
-
-
 def dense_init(key, in_dim: int, out_dim: int, scale: float | None = None) -> jnp.ndarray:
     scale = scale if scale is not None else in_dim**-0.5
     return jax.random.normal(key, (in_dim, out_dim), dtype=jnp.float32) * scale
@@ -440,18 +424,17 @@ def moe_apply(params, cfg, x, act: str = "silu"):
     e, k = cfg.num_experts, cfg.experts_per_token
 
     rules = get_rules()
-    mesh = _abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     batch_axes = (rules or {}).get("batch", "data")
     if not isinstance(batch_axes, tuple):
         batch_axes = (batch_axes,)
     data_size = 1
-    if mesh is not None and not mesh.empty:
+    if not mesh.empty:
         for a in batch_axes:
             if a and a in mesh.shape:
                 data_size *= mesh.shape[a]
     use_ep = (
         rules is not None
-        and mesh is not None
         and not mesh.empty
         and "model" in mesh.shape
         and e % mesh.shape["model"] == 0

@@ -550,6 +550,9 @@ class SmolRuntime:
         self._warm_queue: list[Any] = []
         self._warm_pending = 0
         self._warm_thread: threading.Thread | None = None
+        # failures not yet raised by wait_warm(), and all failures so far
+        self._warm_errors: list[Exception] = []
+        self._warm_failures = 0
 
     # ----------------------------------------------------------- calibration
     def _decode_time(self, fmt: ImageFormat) -> float:
@@ -1114,11 +1117,13 @@ class SmolRuntime:
                 ps = self._warm_queue.pop(0)
             try:
                 ps.warm()
-            except Exception:  # pragma: no cover - backend-dependent
-                # a failed background compile must not kill the warmer; the
-                # affected bucket stays unready and dispatch falls back to
-                # a larger warmed bucket
-                pass
+            except Exception as e:
+                # a failed background compile must not kill the warmer: the
+                # affected bucket stays unready (dispatch falls back to a
+                # larger warmed bucket) and wait_warm() raises the failure
+                with self._warm_cond:
+                    self._warm_errors.append(e)
+                    self._warm_failures += 1
             finally:
                 with self._warm_cond:
                     self._warm_pending -= 1
@@ -1129,11 +1134,19 @@ class SmolRuntime:
         """Block until background bucket warmup has drained (True) or
         ``timeout`` seconds elapsed (False).  Serving is already correct
         before this returns — it gates only full-bucket-granularity
-        batching, not correctness."""
+        batching, not correctness.  Raises ``RuntimeError`` (chained to the
+        first failure) when a background compile has failed since the last
+        call; ``stats().warmup_failures`` keeps the running count."""
         with self._warm_cond:
-            return self._warm_cond.wait_for(
+            done = self._warm_cond.wait_for(
                 lambda: self._warm_pending == 0, timeout=timeout
             )
+            errors, self._warm_errors = self._warm_errors, []
+        if errors:
+            raise RuntimeError(
+                f"{len(errors)} background warmup compile(s) failed: {errors[0]!r}"
+            ) from errors[0]
+        return done
 
     def _release_program_sets(self, compiled: CompiledPlan | None) -> None:
         """Unpin a replaced plan's warm programs — pins live only while
@@ -1976,6 +1989,7 @@ class SmolRuntime:
             cache=cache_section,
             programs_compiled_post_warmup=self._programs_compiled_post_warmup,
             program_compile_seconds_total=self._program_compile_seconds,
+            warmup_failures=self._warm_failures,
         )
 
     # ------------------------------------------------------------- telemetry

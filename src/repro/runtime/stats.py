@@ -186,6 +186,9 @@ class RuntimeStats:
     # compiles after warmup finished, and cumulative compile wall time
     programs_compiled_post_warmup: int = 0
     program_compile_seconds_total: float = 0.0
+    # background warmup compiles that raised, over the runtime's life
+    # (wait_warm() raises each one once)
+    warmup_failures: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         """JSON-safe mapping (stable wire format for the schema version)."""

@@ -1,5 +1,8 @@
 """Pallas kernel sweeps vs. pure-jnp oracles (interpret mode)."""
 
+import inspect
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,10 +11,11 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.fused_preproc.ops import fused_resize_normalize
+from repro.kernels.fused_preproc.ops import fused_resize_affine, fused_resize_normalize
 from repro.kernels.fused_preproc.ref import fused_resize_normalize_ref
 from repro.kernels.idct.ops import dequant_idct
 from repro.kernels.idct.ref import dequant_idct_ref
+from repro.kernels.mode import resolve_interpret
 from repro.preprocessing import dct
 
 RNG = np.random.default_rng(0)
@@ -129,3 +133,15 @@ def test_decode_attention_sweep(b, h, kvh, s, d, window):
     out = decode_attention(q, k, v, lengths, window=window, bk=64)
     ref = decode_attention_ref(q, k, v, lengths, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "wrapper",
+    [dequant_idct, fused_resize_affine, fused_resize_normalize, flash_attention, decode_attention],
+)
+def test_kernel_wrappers_follow_the_backend_rule(wrapper):
+    # no wrapper hard-codes interpret mode: None resolves to compiled on a
+    # TPU and interpreted everywhere else
+    assert inspect.signature(wrapper).parameters["interpret"].default is None
+    assert resolve_interpret(None) == (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False and resolve_interpret(True) is True

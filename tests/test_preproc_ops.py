@@ -8,16 +8,25 @@ from repro.preprocessing import ops as P
 from repro.preprocessing.ops import TensorMeta
 
 
+# one uint8 quantization step through the steepest Normalize std
+QSTEP = (1.0 / 255.0) / 0.224
+
+
 @settings(max_examples=10, deadline=None)
 @given(h=st.integers(40, 200), w=st.integers(40, 200))
 def test_chain_host_device_parity(h, w):
+    # the resample re-quantizes uint8 pixels mid-chain: float association
+    # can flip a value sitting exactly on a rounding tie, so parity is
+    # "within 1e-4 except a vanishing fraction of one-step ties"
     rng = np.random.default_rng(7)
     img = smooth_image(rng, h, w)
     chain = P.STANDARD_RESNET_CHAIN
     yh = P.apply_chain_host(chain, img)
     yd = np.asarray(P.apply_chain_device(chain, img))
     assert yh.shape == (3, 224, 224)
-    assert np.abs(yh - yd).max() < 1e-4
+    diff = np.abs(yh - yd)
+    assert (diff > 1e-4).mean() < 1e-3, f"{(diff > 1e-4).mean():.2e} of pixels off the host chain"
+    assert diff.max() <= QSTEP + 1e-4, "difference exceeds one quantization step"
 
 
 def test_out_meta_tracks_shapes(rng):

@@ -359,6 +359,53 @@ def test_warmup_serving_tail_batch_uses_covering_bucket(corpus):
             assert bucket == ps.bucket_for(s.args["size"])
 
 
+def test_background_warm_failure_is_raised_and_counted(corpus):
+    # a bucket whose compile raises in the background warmer must not be
+    # swallowed: wait_warm() re-raises it and stats() counts it
+    linear = _linear_model(0)
+
+    def model_fn(x):
+        if x.shape[0] < 4:  # trace-time failure: only the small buckets
+            raise ValueError("bucket compile boom")
+        return linear(x)
+
+    rt = _runtime(corpus, warmup="full")
+    rt.model_fns["fast"] = model_fn
+    compiled = rt.compile()  # the full-size bucket warms inline and works
+    with pytest.raises(RuntimeError, match="bucket compile boom"):
+        rt.wait_warm(timeout=60.0)
+    assert rt.stats().warmup_failures == 1
+    ps = compiled.program_sets[0]
+    assert not ps.fully_warm
+    # dispatch still falls back to the warm full-size bucket
+    prog, bucket = ps.program_for(1)
+    assert bucket == ps.max_batch
+
+
+def test_background_warm_failure_is_raised_once(corpus):
+    # wait_warm() raises a failure once; a later warm that succeeds (here a
+    # forced recompile of the same plan) does not raise it again, while
+    # stats() keeps the running count
+    linear = _linear_model(0)
+    failing = [True]
+
+    def model_fn(x):
+        if failing[0] and x.shape[0] < 4:
+            raise ValueError("bucket compile boom")
+        return linear(x)
+
+    rt = _runtime(corpus, warmup="full")
+    rt.model_fns["fast"] = model_fn
+    rt.compile()
+    with pytest.raises(RuntimeError, match="bucket compile boom"):
+        rt.wait_warm(timeout=60.0)
+    failing[0] = False
+    compiled = rt.compile(force=True)
+    assert rt.wait_warm(timeout=60.0)
+    assert compiled.program_sets[0].fully_warm
+    assert rt.stats().warmup_failures == 1
+
+
 def test_warmup_off_is_legacy_lazy_compile(corpus):
     rt = _runtime(corpus, warmup="off")
     compiled = rt.compile()
