@@ -1,0 +1,7 @@
+"""Dequant+IDCT kernel: share of its roofline, % (scan)."""
+
+from smolbench.readers import roofline_pct as _f
+
+
+def read(ctx):
+    return _f(ctx, "idct")
