@@ -431,8 +431,8 @@ class DevicePreprocProgram:
     lowering/wrapping cost paid at compile time; ``first_dispatch_seconds``
     is the wall time of dispatch #1 — jax.jit traces and XLA-compiles
     synchronously on first call, so this is the cold-start cost a request
-    that misses the program cache actually experiences (telemetry tags the
-    dispatch span with it).
+    that misses the program cache actually experiences (the ``smol.compile``
+    profiler span covers it).
     """
 
     fn: Callable[[Any], Any]  # jitted (batch,) -> model outputs
@@ -475,9 +475,13 @@ class DevicePreprocProgram:
 
     def __call__(self, batch):
         if self.dispatch_count == 0:
+            # deferred: repro.core stays importable without repro.runtime
+            from repro.runtime.telemetry import span
+
             t0 = time.perf_counter()
-            out = self.fn(_place(batch, self.device))
-            jax.block_until_ready(out)
+            with span("smol.compile", batch=self.batch_size):
+                out = self.fn(_place(batch, self.device))
+                jax.block_until_ready(out)
             self.first_dispatch_seconds = time.perf_counter() - t0
             # counted only once it ran: a compile that raised leaves the
             # program unready, so a ProgramSet never serves it

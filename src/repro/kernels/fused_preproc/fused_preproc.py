@@ -78,4 +78,5 @@ def fused_resize_normalize_planar(
         out_specs=pl.BlockSpec((1, tile_oh, ow), lambda ci, oi: (ci, oi, 0)),
         out_shape=jax.ShapeDtypeStruct((c, oh_pad, ow), jnp.float32),
         interpret=interpret,
+        name="fused_resize_normalize_planar",  # the op name device traces show for this kernel
     )(x, ry, rxt, scale, bias)

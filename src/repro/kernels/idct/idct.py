@@ -62,4 +62,5 @@ def dequant_idct_tiles(
         out_specs=pl.BlockSpec((tile, 64), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 64), jnp.float32),
         interpret=interpret,
+        name="dequant_idct_tiles",  # the op name device traces show for this kernel
     )(coeffs_flat, m2q_t)

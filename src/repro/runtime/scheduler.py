@@ -175,8 +175,17 @@ class SchedulerStats:
     batches: int = 0
     batch_items: int = 0
     host_items: int = 0  # items through the host stage (>= completed)
-    host_busy_seconds: float = 0.0
+    host_busy_seconds: float = 0.0  # wall time inside host_fn, summed over workers
+    host_cpu_seconds: float = 0.0  # the same calls' thread CPU time
+    # wall time of the blocking device call (about launch_seconds +
+    # readback_seconds), not the device's own busy time
     device_busy_seconds: float = 0.0
+    # the replica batchers' phases, summed over batchers (the smol.*
+    # profiler spans of the same names cover the same intervals)
+    starved_seconds: float = 0.0  # blocked on the ready queue, nothing staged
+    batch_form_seconds: float = 0.0  # co-member waits and staging copies
+    launch_seconds: float = 0.0  # device_fn call: placement, H2D, enqueue
+    readback_seconds: float = 0.0  # device completion, D2H, interpreter back
     admission_blocked_seconds: float = 0.0  # time submit() spent backpressured
     replica_failures: int = 0  # replicas lost from the serving mesh
     redispatched_items: int = 0  # items drained off failed replicas + re-served
@@ -952,17 +961,21 @@ class RequestScheduler:
             # tenant whose request is being staged
             set_current_tenant(state.config.name)
             t_in = time.perf_counter()
+            cpu_in = time.thread_time()
             try:
-                arr = host_fn(item)
+                with self.telemetry.span("smol.decode", uid=uid, worker=wid):
+                    arr = host_fn(item)
             except BaseException as e:  # noqa: BLE001 — delivered via drain()
                 self._complete_error(state, uid, tm, e, route)
                 continue
+            cpu = time.thread_time() - cpu_in
             dt = time.perf_counter() - t_in
             tm.decoded = time.perf_counter()
             tm.worker = wid
             self.telemetry.observe_host(state.config.name, dt)
             with self._stats_lock:
                 self.stats.host_busy_seconds += dt
+                self.stats.host_cpu_seconds += cpu
                 self.stats.host_items += 1
                 state.stats.host_busy_seconds += dt
                 state.stats.host_items += 1
@@ -1019,7 +1032,12 @@ class RequestScheduler:
                 if not self._form_batch(bufs, replica, wait=True):
                     return
                 continue
-            msg = self._ready.get()
+            t_wait = time.perf_counter()
+            with self.telemetry.span("smol.starved", replica=replica.index):
+                msg = self._ready.get()
+            starved = time.perf_counter() - t_wait
+            with self._stats_lock:
+                self.stats.starved_seconds += starved
             if msg is self._STOP:
                 self._drain_pending(bufs, replica)
                 return
@@ -1049,10 +1067,28 @@ class RequestScheduler:
     def _form_batch(self, bufs: dict, replica: _ReplicaState, wait: bool) -> bool:
         """Form and dispatch ONE batch by weighted-fair pick.  Returns False
         when a stop sentinel was consumed (caller must exit)."""
+        t_form = time.perf_counter()
+        with self.telemetry.span("smol.batch_form", replica=replica.index):
+            formed = self._gather(bufs, replica, wait)
+        if formed is None:
+            return True
+        binding, buf, metas, t_open, stopped = formed
+        form_s = time.perf_counter() - t_form
+        self._dispatch(binding, buf, metas, replica, t_open, form_s)
+        if stopped:
+            self._drain_pending(bufs, replica)
+            return False
+        return True
+
+    def _gather(self, bufs: dict, replica: _ReplicaState, wait: bool):
+        """Stage ONE batch's members by weighted-fair pick, waiting up to the
+        batch deadline for co-members.  Returns ``(binding, buf, metas,
+        t_open, stopped)`` (``stopped``: a stop sentinel was consumed), or
+        None when nothing is ready."""
         with self._ready_lock:
             active = [s for s in self._tenants.values() if s.ready]
             if not active:
-                return True
+                return None
             first = self._pick_ready(active)
             binding = self._entry_binding(first, first.ready[0])
             head = first.ready.popleft()
@@ -1098,9 +1134,7 @@ class RequestScheduler:
             except queue.Empty:
                 break
             if msg is self._STOP:
-                self._dispatch(binding, buf, metas, replica, t_open)
-                self._drain_pending(bufs, replica)
-                return False
+                return binding, buf, metas, t_open, True
             if msg is self._KICK:
                 continue
             self._stash(msg)
@@ -1111,8 +1145,7 @@ class RequestScheduler:
                 leftover = any(s.ready for s in self._tenants.values())
             if leftover:
                 self._ready.put(self._KICK)
-        self._dispatch(binding, buf, metas, replica, t_open)
-        return True
+        return binding, buf, metas, t_open, False
 
     def _drain_pending(self, bufs: dict, replica: _ReplicaState) -> None:
         """Dispatch whatever is still staged in tenant deques (stop path).
@@ -1202,8 +1235,11 @@ class RequestScheduler:
         buf: np.ndarray,
         metas: list,
         replica: _ReplicaState,
-        t_open: float | None = None,
+        t_open: float,
+        form_s: float,
     ) -> None:
+        """Launch one formed batch, read it back and retire it.  ``t_open``
+        is when the batch opened, ``form_s`` how long forming it took."""
         if not metas:
             return
         if self._fail_exc is not None:
@@ -1220,14 +1256,20 @@ class RequestScheduler:
         t_in = time.perf_counter()
         with self._rebind_lock:
             device_fn, bucket = binding.dispatch_fn_for(replica.index, len(metas))
+        # ragged batch + AOT program set: slice to the smallest warm bucket
+        # covering the batch; unbucketed dispatch runs the full max_batch
+        # buffer.  Either way padding lanes stop here — the completion
+        # below reads only rows < len(metas).
+        staged = buf if bucket is None else buf[:bucket]
+        seq = replica.batches + 1  # this replica's batch sequence number
+        span = self.telemetry.span
         try:
-            # ragged batch + AOT program set: slice to the smallest warm
-            # bucket covering the batch; unbucketed dispatch runs the full
-            # max_batch buffer.  Either way padding lanes stop here — the
-            # completion loop below reads only rows < len(metas).
-            out = np.asarray(
-                device_fn(buf if bucket is None else buf[:bucket])
-            )  # blocks until device done
+            with span("smol.launch", replica=replica.index, batch=seq,
+                      bucket=len(staged), bytes=staged.nbytes):
+                out = device_fn(staged)
+            t_launched = time.perf_counter()
+            with span("smol.readback", replica=replica.index, batch=seq):
+                out = np.asarray(out)  # blocks until device done
         except ReplicaFailure as e:
             self._on_replica_failure(replica, metas, e)
             return
@@ -1235,8 +1277,27 @@ class RequestScheduler:
             for uid, tm, state, _arr, route in metas:
                 self._complete_error(state, uid, tm, e, route)
             return
-        dt = time.perf_counter() - t_in
         now = time.perf_counter()
+        with span("smol.complete", replica=replica.index, batch=seq):
+            self._retire(metas, out, replica, device_fn, bucket, t_open, now,
+                         (form_s, t_launched - t_in, now - t_launched))
+
+    def _retire(
+        self,
+        metas: list,
+        out: np.ndarray,
+        replica: _ReplicaState,
+        device_fn,
+        bucket: int | None,
+        t_open: float,
+        now: float,
+        phases: tuple[float, float, float],
+    ) -> None:
+        """Route one read-back batch's rows: counters, the reorder buffer or
+        a route's sink, admissions, refetches.  ``phases`` are the batch's
+        formation, launch and readback seconds."""
+        form_s, launch_s, readback_s = phases
+        dt = launch_s + readback_s
         per_tenant = collections.Counter(state.config.name for _, _, state, _, _ in metas)
         states = {state.config.name: state for _, _, state, _, _ in metas}
         tel = self.telemetry
@@ -1276,14 +1337,13 @@ class RequestScheduler:
                 "batch",
                 None,
                 tel.next_batch_id(),
-                t_open if t_open is not None else t_in,
+                t_open,
                 now,
                 replica=replica.index,
                 size=len(metas),
                 bucket=bucket,
                 uids=[m[0] for m in metas],
                 cold=getattr(device_fn, "dispatch_count", 0) == 1,
-                compile_s=getattr(device_fn, "first_dispatch_seconds", None),
             )
             for state, uid, tm, route, _nxt in refetch:
                 # the cheap-stage pass this item just finished before its
@@ -1299,6 +1359,9 @@ class RequestScheduler:
                 )
         with self._stats_lock:
             self.stats.device_busy_seconds += dt
+            self.stats.batch_form_seconds += form_s
+            self.stats.launch_seconds += launch_s
+            self.stats.readback_seconds += readback_s
             self.stats.batches += 1
             self.stats.batch_items += len(metas)
             self.stats.completed += len(finish)

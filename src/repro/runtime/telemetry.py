@@ -19,10 +19,16 @@ drain, recording
   (:meth:`measurement_window`) — the scheduler's previous ad-hoc
   ``time.perf_counter()`` snapshot bookkeeping now lives here, fed by the
   same observations the histograms see.
+* **per-thread phase spans on the profiler clock** (:func:`span`): while a
+  ``jax.profiler`` session is active, each runtime thread's phases (host
+  decode, batcher starvation, batch formation, launch, readback,
+  completion, first-dispatch compile) go into the profiler's own trace as
+  ``smol.*`` annotations, beside the device ops and on their clock.  With
+  no session active a span records nothing.
 * **span capture** (opt-in via :class:`TelemetryConfig`): full per-request
   span timelines — queue/decode/stage/dispatch/drain tile the request's
   wall latency exactly, batch spans link their member requests and carry
-  replica id + cold-start compile visibility — recorded into *per-thread
+  replica id + cold-start visibility — recorded into *per-thread
   ring buffers* (no locks, no allocation on the hot path beyond the ring
   itself, created lazily per thread).  :meth:`dump_trace` writes Chrome
   trace-event JSON loadable in Perfetto, with tenants and the replica mesh
@@ -36,6 +42,7 @@ the acceptance test holds to within 10%.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -44,10 +51,30 @@ import time
 from typing import Any, Iterable, Mapping
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-#: the shared telemetry clock — every stage timestamp in the runtime comes
-#: from this one monotonic source, so spans from different threads compose
+#: the clock of the request timelines, ring spans and occupancy counters —
+#: one monotonic source, so their timestamps compose across threads.  The
+#: profiler spans (:func:`span`) carry the profiler's own clock instead.
 clock = time.perf_counter
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **args: Any):
+    """One host phase as a context manager on the profiler's clock.
+
+    While a ``jax.profiler`` session is active (``jax.profiler.trace(dir)``,
+    ``start_trace``), the phase is a ``TraceAnnotation`` on the calling
+    thread's line of the host plane, in the same trace as the device ops;
+    ``args`` (ids only: uid, worker, batch sequence number, bucket, bytes)
+    become its stats.  Otherwise it is a shared no-op context that records
+    nothing, at the cost of one ``is_enabled()`` check.
+    """
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **args)
+    return _NO_SPAN
+
 
 # The request timeline, in pipeline order.  Each stage's span starts where
 # the previous one ended:
@@ -285,6 +312,7 @@ class Telemetry:
     """
 
     clock = staticmethod(clock)
+    span = staticmethod(span)
 
     def __init__(self, config: TelemetryConfig | None = None):
         self.config = config or TelemetryConfig()

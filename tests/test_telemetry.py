@@ -1,29 +1,41 @@
 """Telemetry subsystem: streaming-histogram quantile accuracy, span rings
 and the zero-allocation telemetry-off guard, traced-request span tiling,
-occupancy measurement windows, and the trace/metrics export surfaces.
+occupancy measurement windows, the trace/metrics export surfaces, and the
+runtime's ``smol.*`` phase spans in a ``jax.profiler`` trace beside the
+scheduler's phase counters.
 
 Timing tests use sleep-controlled stage functions (policy, not box
 throughput); distribution tests check the histogram against exact
 percentiles of the same samples.
 """
 
+import glob
 import json
 import time
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
+from conftest import smooth_image
+from repro.core.planner import ModelSpec
+from repro.preprocessing.formats import ImageFormat, StoredImage
 from repro.runtime import (
+    ClassificationQuery,
+    DeviceCompilerConfig,
     HistogramSummary,
     LatencySection,
     RequestScheduler,
+    RuntimeConfig,
     RuntimeStats,
+    SmolRuntime,
     StreamingHistogram,
     Telemetry,
     TelemetryConfig,
     TenantConfig,
 )
+from repro.runtime import telemetry as telemetry_mod
 from repro.runtime.telemetry import REQUEST_STAGES, _SpanRing
 
 
@@ -313,3 +325,125 @@ def test_stats_dict_access_warns_even_under_error_filter():
         assert stats.get("no_such_section", 42) == 42
         with pytest.raises(KeyError):
             stats["no_such_section"]
+
+
+# ------------------------------------------------- profiler-clock phase spans
+BATCHER_SPANS = ("smol.starved", "smol.batch_form", "smol.launch", "smol.readback", "smol.complete")
+SPAN_NAMES = ("smol.decode", "smol.compile") + BATCHER_SPANS
+WINDOW = "test.serving"
+
+
+@pytest.fixture(scope="module")
+def profiled_serving(tmp_path_factory):
+    """One tiny served ``submit()``/``drain()`` run over split-decoded JPEG
+    (warmup off, so dispatch #1 compiles while serving) inside an enclosing
+    annotation, under a ``jax.profiler`` trace.  Returns the window, the host
+    plane's events per line (one line per thread) and the scheduler's
+    counters."""
+    fmt = ImageFormat("jpeg", None, 95)
+    rng = np.random.default_rng(5)
+    corpus = [StoredImage.from_array(smooth_image(rng, 72, 88), [fmt]) for _ in range(24)]
+    w = np.asarray(jax.random.normal(jax.random.PRNGKey(0), (3 * 32 * 32, 5)) * 0.02)
+    rt = SmolRuntime(
+        [ModelSpec("m", 32, exec_throughput=50_000.0, accuracy_by_format={fmt.key: 0.9})],
+        [fmt],
+        {"m": lambda x: x.reshape(x.shape[0], -1) @ w},
+        calibration=corpus[:3],
+        config=RuntimeConfig(
+            batch_size=4,
+            num_workers=2,
+            max_wait_ms=2.0,
+            device=DeviceCompilerConfig(backend="fused", split_decode="full"),
+        ),
+        decode_time=lambda fmt: 1e-4,
+    )
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=opts):
+        with jax.profiler.TraceAnnotation(WINDOW):
+            rt.start_serving()
+            try:
+                for wave in (corpus[:12], corpus[12:]):
+                    for item in wave:
+                        rt.submit(ClassificationQuery(image=item))
+                    rt.flush(timeout=120.0)
+                    time.sleep(0.05)  # the batcher starves between waves
+                done = rt.drain()
+                stats = rt.stats().scheduler.stats
+            finally:
+                rt.stop_serving()
+    assert len(done) == 24 and not any(r.error for r in done)
+    (path,) = glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb")
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                lines.append([(e.name, e.start_ns, e.end_ns) for e in line.events])
+    (window,) = [(s, e) for line in lines for n, s, e in line if n == WINDOW]
+    return window, lines, stats
+
+
+def test_profiler_trace_holds_every_phase_span_inside_the_window(profiled_serving):
+    (lo, hi), lines, _stats = profiled_serving
+    seen = {n for line in lines for n, s, e in line if lo <= s and e <= hi}
+    assert set(SPAN_NAMES) <= seen, set(SPAN_NAMES) - seen
+    # one thread's line carries every batcher phase; the decode spans sit
+    # on the host workers' lines, apart from it
+    batcher = [line for line in lines if any(n == "smol.launch" for n, _s, _e in line)]
+    assert len(batcher) == 1
+    assert set(BATCHER_SPANS) <= {n for n, _s, _e in batcher[0]}
+    assert not any(n == "smol.decode" for n, _s, _e in batcher[0])
+
+
+def test_batcher_spans_tile_the_batcher_thread(profiled_serving):
+    _window, lines, _stats = profiled_serving
+    (line,) = [line for line in lines if any(n == "smol.launch" for n, _s, _e in line)]
+    phases = sorted((s, e) for n, s, e in line if n in BATCHER_SPANS)
+    # from the end of the first batch, whose launch compiled the program, to
+    # the end of the last: the compile would hide a gap between phases
+    completes = sorted(e for n, _s, e in line if n == "smol.complete")
+    assert len(completes) >= 3
+    lo, hi = completes[0], completes[-1]
+    covered, reach = 0.0, lo
+    for s, e in phases:
+        s, e = max(s, reach), min(e, hi)
+        if e > s:
+            covered += e - s
+            reach = e
+    assert covered >= 0.9 * (hi - lo), covered / (hi - lo)
+
+
+def test_phase_counters_split_the_blocking_call(profiled_serving):
+    _window, _lines, st = profiled_serving
+    assert st.batches > 0 and st.launch_seconds > 0 and st.readback_seconds > 0
+    assert st.batch_form_seconds > 0 and st.starved_seconds > 0
+    split = st.launch_seconds + st.readback_seconds
+    assert abs(split - st.device_busy_seconds) <= 0.05 * st.device_busy_seconds
+    assert 0 < st.host_cpu_seconds <= st.host_busy_seconds + 1e-3
+
+
+def test_no_profiler_session_records_no_span_and_no_ring(monkeypatch):
+    made = []
+
+    class Counting:
+        is_enabled = staticmethod(telemetry_mod.TraceAnnotation.is_enabled)
+
+        def __init__(self, name, **args):
+            made.append(name)
+
+    monkeypatch.setattr(telemetry_mod, "TraceAnnotation", Counting)
+    assert not Counting.is_enabled()
+    assert telemetry_mod.span("smol.decode", uid=1) is telemetry_mod.span("smol.launch")
+    tel = Telemetry()
+    sched = _sched(tel, host_sleep=0.0, device_sleep=0.0)
+    try:
+        for i in range(16):
+            sched.submit(i)
+        sched.flush(timeout=30.0)
+        done = sched.drain()
+    finally:
+        sched.stop()
+    assert len(done) == 16
+    assert made == [] and tel.ring_allocations == 0
+    assert sched.stats.launch_seconds > 0 and sched.stats.host_items == 16

@@ -8,6 +8,7 @@ importing this module never loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,16 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _kernel_names(compiled) -> set[str]:
+    """Instruction names of the Pallas calls, without their numeric suffix:
+    the op names a device trace shows for the kernels."""
+    return {
+        re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)", ln).group(1)
+        for ln in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in ln
+    }
+
+
 def test_idct_kernel_compiles_at_a_served_block_count(one_chip):
     # batch 32 of 256x256 4:2:0: 32 * (1024 luma + 512 chroma) 8x8 blocks
     n = 32 * 1536
@@ -62,6 +73,7 @@ def test_idct_kernel_compiles_at_a_served_block_count(one_chip):
         lambda c: dequant_idct(c, q, interpret=False), one_chip, ((n, 8, 8), jnp.float32)
     )
     assert _custom_calls(compiled) == 1
+    assert _kernel_names(compiled) == {"dequant_idct_tiles"}
 
 
 @pytest.mark.parametrize("h,w", [(256, 256), (375, 500)])
@@ -77,6 +89,7 @@ def test_resample_kernel_compiles(one_chip, h, w):
 
     compiled = _compile(fn, one_chip, ((planes, h, w), jnp.float32))
     assert _custom_calls(compiled) == 1
+    assert _kernel_names(compiled) == {"fused_resize_normalize_planar"}
 
 
 def test_resample_whole_plane_exceeds_vmem_at_1080p(one_chip):
@@ -113,3 +126,5 @@ def test_coefficient_program_compiles_with_both_kernels(one_chip):
     compiled = prog.fn.lower(jax.ShapeDtypeStruct(shape, jnp.int16, sharding=one_chip)).compile()
     # one IDCT call per quant table plus the fused resample
     assert _custom_calls(compiled) == 3
+    # inside the whole program the kernels keep the op names a trace reads
+    assert _kernel_names(compiled) == {"dequant_idct_tiles", "fused_resize_normalize_planar"}
