@@ -117,12 +117,15 @@ def decompress_into(blob, out) -> int:
     raise ValueError(f"unknown compression method tag {method:#x}")
 
 
-def decompress(blob: bytes) -> bytes:
-    """Inverse of :func:`compress`; raises if the method is unavailable."""
+def decompress(blob) -> bytes | memoryview:
+    """Inverse of :func:`compress`; raises if the method is unavailable.
+
+    ``blob`` is any bytes-like object and is not copied: a STORED payload
+    comes back as a view of it, a zstd frame as fresh bytes."""
     if len(blob) == 0:
         raise ValueError("empty compressed payload")
     method = blob[0]
-    payload = bytes(blob[1:])
+    payload = memoryview(blob)[1:]
     if method == STORED:
         return payload
     if method == ZSTD:

@@ -34,7 +34,7 @@ import struct
 
 import numpy as np
 
-from repro.preprocessing import compression, dct, scratch as scratch_mod
+from repro.preprocessing import compression, dct
 
 MAGIC = b"SJPG"
 VERSION = 2  # v2: band payloads framed by preprocessing.compression method tags
@@ -140,13 +140,9 @@ def _encode_rows_sparse(zz_rows: np.ndarray) -> bytes:
     return b"".join(parts)
 
 
-def _decode_rows_sparse(
-    buf, off: int, scratch: "scratch_mod.BandScratch | None" = None
-) -> tuple[np.ndarray, int]:
-    """Inverse of :func:`_encode_rows_sparse`; returns (n_blocks, 64) int16.
-
-    With ``scratch`` the coefficient buffer is an arena slice (released by
-    the caller's band_scratch scope) instead of a fresh allocation."""
+def _split_rows_sparse(buf, off: int) -> tuple[tuple[np.ndarray, ...], int]:
+    """Views ``(dc, counts, pos, vals)`` of the segment that
+    :func:`_encode_rows_sparse` wrote at ``off``, and the offset after it."""
     (n_blocks,) = struct.unpack_from("<I", buf, off)
     off += 4
     dc = np.frombuffer(buf, dtype="<i2", count=n_blocks, offset=off)
@@ -157,14 +153,32 @@ def _decode_rows_sparse(
     pos = np.frombuffer(buf, dtype=np.uint8, count=nnz, offset=off)
     off += nnz
     vals = np.frombuffer(buf, dtype="<i2", count=nnz, offset=off)
-    off += 2 * nnz
-    if scratch is not None:
-        zz = scratch.alloc((n_blocks, 64), np.int16)
-    else:
-        zz = np.zeros((n_blocks, 64), dtype=np.int16)
-    zz[:, 0] = dc
-    blk_idx = np.repeat(np.arange(n_blocks), counts)
-    zz[blk_idx, pos.astype(np.int64)] = vals
+    return (dc, counts, pos, vals), off + 2 * nnz
+
+
+def _scatter_rows_sparse(out: np.ndarray, segments, starts) -> None:
+    """Write split segments into the zeroed ``out`` (n_blocks, 64) int16,
+    segment ``k``'s blocks from row ``starts[k]`` on.
+
+    One scatter writes every DC and one every AC value, whatever the number
+    of segments: each small numpy call holds the interpreter, so the count
+    of calls, not the bytes, sets the host cost of the entropy stage."""
+    dc, counts, pos, vals = (np.concatenate(part) for part in zip(*segments))
+    sizes = [len(seg[0]) for seg in segments]
+    first = np.cumsum([0] + sizes[:-1])
+    base = (np.arange(len(dc)) + np.repeat(np.asarray(starts) - first, sizes)) * 64
+    flat = out.reshape(-1)
+    flat[base] = dc
+    ac = np.repeat(base, counts)
+    ac += pos
+    flat[ac] = vals
+
+
+def _decode_rows_sparse(buf, off: int) -> tuple[np.ndarray, int]:
+    """Inverse of :func:`_encode_rows_sparse`; returns (n_blocks, 64) int16."""
+    seg, off = _split_rows_sparse(buf, off)
+    zz = np.zeros((len(seg[0]), 64), dtype=np.int16)
+    _scatter_rows_sparse(zz, [seg], [0])
     return zz, off
 
 
@@ -241,39 +255,6 @@ def peek_header(data: bytes) -> JpegHeader:
     return JpegHeader(h, w, ch, q, bool(sub), band_rows, n_br, n_bc, tuple(band_offsets), off)
 
 
-def _decode_band_coeffs(
-    data: bytes,
-    hdr: JpegHeader,
-    band: int,
-    scratch: "scratch_mod.BandScratch | None" = None,
-) -> list[np.ndarray]:
-    """Entropy-decode one band -> per-plane zigzagged (rows, n_bc, 64) int16.
-
-    With ``scratch`` both the decompressed payload and the coefficient
-    buffers come from the caller's arena scope (no per-band allocations)."""
-    start = hdr.payload_start + hdr.band_offsets[band]
-    end = hdr.payload_start + (
-        hdr.band_offsets[band + 1] if band + 1 < hdr.n_bands else len(data) - hdr.payload_start
-    )
-    blob = memoryview(data)[start:end]
-    raw = None
-    if scratch is not None:
-        size = compression.decompressed_size(blob)
-        if size is not None:
-            buf = scratch.alloc_bytes(size)
-            n = compression.decompress_into(blob, buf)
-            raw = buf[:n]
-    if raw is None:
-        raw = memoryview(compression.decompress(bytes(blob)))
-    grids = _plane_grids(hdr)
-    ranges = _band_plane_rows(hdr, band)
-    out, off = [], 0
-    for (n_br_p, n_bc_p), (r0, r1) in zip(grids, ranges):
-        zz, off = _decode_rows_sparse(raw, off, scratch=scratch)
-        out.append(zz.reshape(r1 - r0, n_bc_p, 64))
-    return out
-
-
 def decode_to_coefficients(
     data: bytes,
     roi: tuple[int, int, int, int] | None = None,
@@ -287,6 +268,12 @@ def decode_to_coefficients(
     the half-open block-row range each plane covers.  Dequantization and the
     IDCT — the dense, MXU-friendly stage — are left to the caller so they can
     be placed on host or device (kernels/idct/ops.py).
+
+    The planes are views of one int16 buffer allocated per call, laid out
+    plane after plane (Y, Cb, Cr), so the packed staging layout is one copy.
+    Each band's segments land at their own block rows: with 4:2:0 and an odd
+    ``band_rows`` two bands share a chroma row, which is written once per
+    band with the same values.
     """
     hdr = peek_header(data)
     lo_row, hi_row = 0, hdr.n_br
@@ -302,27 +289,35 @@ def decode_to_coefficients(
     hi_band = (hi_row + hdr.band_rows - 1) // hdr.band_rows
     hi_band = min(hi_band, hdr.n_bands)
 
-    per_plane: list[list[np.ndarray]] = [[] for _ in _plane_grids(hdr)]
-    plane_ranges: list[list[int]] = [[1 << 30, 0] for _ in per_plane]
-    # per-band payload + coefficient scratch lives in the thread-local
-    # FrameArena for the duration of the loop: steady-state decode makes
-    # zero per-band system allocations (only the concatenated result below
-    # is caller-owned memory)
-    with scratch_mod.band_scratch() as scratch:
-        for band in range(lo_band, hi_band):
-            coeffs = _decode_band_coeffs(data, hdr, band, scratch=scratch)
-            ranges = _band_plane_rows(hdr, band)
-            for p, (c, (r0, r1)) in enumerate(zip(coeffs, ranges)):
-                per_plane[p].append(c)
-                plane_ranges[p][0] = min(plane_ranges[p][0], r0)
-                plane_ranges[p][1] = max(plane_ranges[p][1], r1)
-        planes_zz = [
-            np.concatenate(chunks, axis=0) if chunks else np.zeros((0, g[1], 64), np.int16)
-            for chunks, g in zip(per_plane, _plane_grids(hdr))
-        ]
-    qtables = _qtables(hdr.quality, hdr.channels)
-    row_ranges = [tuple(r) for r in plane_ranges]
-    return hdr, planes_zz, qtables, row_ranges
+    grids = _plane_grids(hdr)
+    if lo_band < hi_band:
+        firsts = _band_plane_rows(hdr, lo_band)
+        lasts = _band_plane_rows(hdr, hi_band - 1)
+        row_ranges = [(r0, r1) for (r0, _), (_, r1) in zip(firsts, lasts)]
+    else:
+        row_ranges = [(0, 0)] * len(grids)
+    sizes = [(r1 - r0) * n_bc for (r0, r1), (_, n_bc) in zip(row_ranges, grids)]
+    bases = np.cumsum([0] + sizes[:-1]).tolist()
+    coeffs = np.zeros((sum(sizes), 64), dtype=np.int16)
+
+    segments, starts = [], []
+    for band in range(lo_band, hi_band):
+        start = hdr.payload_start + hdr.band_offsets[band]
+        end = hdr.payload_start + hdr.band_offsets[band + 1] if band + 1 < hdr.n_bands else len(data)
+        raw, off = compression.decompress(memoryview(data)[start:end]), 0
+        for (r0, _), (lo, _), base, (_, n_bc) in zip(
+            _band_plane_rows(hdr, band), row_ranges, bases, grids
+        ):
+            seg, off = _split_rows_sparse(raw, off)
+            segments.append(seg)
+            starts.append(base + (r0 - lo) * n_bc)
+    if segments:
+        _scatter_rows_sparse(coeffs, segments, starts)
+    planes_zz = [
+        coeffs[base : base + size].reshape(r1 - r0, n_bc, 64)
+        for base, size, (r0, r1), (_, n_bc) in zip(bases, sizes, row_ranges, grids)
+    ]
+    return hdr, planes_zz, _qtables(hdr.quality, hdr.channels), row_ranges
 
 
 @functools.lru_cache(maxsize=1024)

@@ -1,20 +1,18 @@
 """Arena-backed scratch for codec band payloads (ROADMAP: arena codecs).
 
-The SJPG/SPNG codecs decode band-by-band: each band needs a decompressed
-payload buffer and (for SJPG) a dense coefficient buffer, all dead as soon
-as the bands are concatenated into the caller's result.  Before this
-module, every band hit the system allocator; at serving rates that
-allocator traffic is exactly what "Beyond Inference" measures dominating
-host-side cost.  Now per-band scratch is a bump-pointer slice from a
-thread-local :class:`repro.runtime.memory.FrameArena` — steady-state decode
-touches the allocator zero times (each producer worker thread owns its own
-arena, so there is no cross-worker lock traffic either).
+The SPNG codec decodes band by band: each band needs a decompressed
+payload buffer, dead as soon as the bands are concatenated into the
+caller's result.  Per-band scratch is a bump-pointer slice from a
+thread-local :class:`repro.runtime.memory.FrameArena`, so steady-state
+decode touches the allocator zero times (each producer worker thread owns
+its own arena, so there is no cross-worker lock traffic either).  SJPG
+needs none of it: it entropy-decodes every band of an item into one
+coefficient buffer allocated per call (``jpeg.decode_to_coefficients``).
 
 Usage (inside a codec):
 
     with band_scratch() as scratch:
         buf = scratch.alloc_bytes(n)          # uint8 view
-        zz = scratch.alloc((blocks, 64), np.int16)  # zero-filled typed view
         ...  # slices all release when the block exits
 
 The arena import is deferred so ``repro.preprocessing`` stays importable
@@ -61,17 +59,6 @@ class BandScratch:
         sl = _arena().alloc(-(-nbytes // 64) * 64)
         self._slices.append(sl)
         return sl.array[:nbytes]
-
-    def alloc(self, shape: tuple[int, ...], dtype, zero: bool = True) -> np.ndarray:
-        """Typed scratch view; zero-filled by default (arena memory is
-        recycled, so callers relying on np.zeros semantics need the fill)."""
-        dtype = np.dtype(dtype)
-        nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
-        raw = self.alloc_bytes(nbytes)
-        view = raw[:nbytes].view(dtype).reshape(shape)
-        if zero:
-            view.fill(0)
-        return view
 
     def release(self) -> None:
         slices, self._slices = self._slices, []

@@ -126,6 +126,75 @@ def test_decode_scaled_grayscale_and_odd_sizes(rng):
         jpeg.decode_scaled(blob, 3)
 
 
+def _encoder_coefficients(img, subsample):
+    """The quantised zigzag planes the encoder codes, (rows, cols, 64) each."""
+    if img.ndim == 2:
+        planes = [img.astype(np.float64)]
+    else:
+        ycc = dct.rgb_to_ycbcr(img)
+        planes = [ycc[..., 0]]
+        for c in (1, 2):
+            p = ycc[..., c]
+            if subsample:
+                p = np.pad(p, ((0, p.shape[0] % 2), (0, p.shape[1] % 2)), mode="edge")
+                p = p.reshape(p.shape[0] // 2, 2, p.shape[1] // 2, 2).mean(axis=(1, 3))
+            planes.append(p)
+    qtables = jpeg._qtables(90, len(planes))
+    out = []
+    for plane, qt in zip(planes, qtables):
+        zz, n_br, n_bc = jpeg._quantize_plane(plane - 128.0, qt)
+        out.append(zz.reshape(n_br, n_bc, 64))
+    return out
+
+
+_COEFF_CASES = [
+    (layout, band_rows, region, False)
+    for layout in ("420", "444", "gray")
+    for band_rows in (3, 4, 5)
+    for region in ("full", "roi", "max_rows")
+] + [("420", 3, "full", True), ("420", 4, "roi", True)]
+
+
+@pytest.mark.parametrize("layout,band_rows,region,stored", _COEFF_CASES)
+def test_decode_to_coefficients_returns_encoder_coefficients(
+    monkeypatch, layout, band_rows, region, stored
+):
+    # 100x90 at 4:2:0 has a 7-row chroma grid; an odd band_rows makes
+    # adjacent bands share a chroma block row, which must appear once
+    from repro.preprocessing import compression
+
+    rng = np.random.default_rng(7)
+    img = smooth_image(rng, 100, 90)
+    img = np.clip(img + rng.integers(-20, 21, img.shape), 0, 255).astype(np.uint8)
+    if layout == "gray":
+        img = img[..., 0]
+    subsample = layout == "420"
+    with monkeypatch.context() as m:
+        if stored:
+            m.setattr(compression, "_zstd", None)
+        blob = jpeg.encode(img, quality=90, subsample=subsample, band_rows=band_rows)
+    hdr = jpeg.peek_header(blob)
+    if stored:
+        assert blob[hdr.payload_start] == compression.STORED
+
+    kw = {"full": {}, "roi": {"roi": (40, 8, 72, 50)}, "max_rows": {"max_rows": 45}}[region]
+    hdr, planes_zz, _, row_ranges = jpeg.decode_to_coefficients(blob, **kw)
+    expected = _encoder_coefficients(img, subsample)
+    assert len(planes_zz) == len(expected) == len(row_ranges)
+    grids = [(hdr.n_br, hdr.n_bc)] + [jpeg.chroma_grid(hdr)] * (len(planes_zz) - 1)
+    for zz, want, (r0, r1), grid in zip(planes_zz, expected, row_ranges, grids):
+        assert want.shape[:2] == grid
+        assert zz.dtype == np.int16 and zz.shape == (r1 - r0, grid[1], 64)
+        np.testing.assert_array_equal(zz, want[r0:r1])
+    lo, hi = row_ranges[0]
+    if region == "full":
+        assert all(r == (0, g[0]) for r, g in zip(row_ranges, grids))
+    elif region == "roi":  # whole bands covering pixel rows 40..72
+        assert lo * 8 <= 40 and hi * 8 >= 72 and lo % band_rows == 0
+    else:
+        assert lo == 0 and 6 <= hi < 6 + band_rows
+
+
 @pytest.mark.parametrize("subsample", [False, True])
 def test_stage_coefficients_layouts_roundtrip(rng, subsample):
     # both staging layouts carry the same blocks; the padded layout's
