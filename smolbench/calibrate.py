@@ -17,9 +17,9 @@ way; and both readings' ``pixel_off_share`` at other tie bands
 corpus items' reference logits, on the measure of ``logit_gap``
 (``item_separation``).
 
-For each of ``--int8-seeds``: the runtime rebuilt with the reference network
-at int8 operands served in the program's place, driven and compared as a run
-is (``control_int8``).
+For each of ``--int8-seeds``: the runtime rebuilt with the family's reference
+network at int8 operands served in the program's place, driven and compared as
+a run is (``control_int8``).
 
 One JSON line per reading on standard output.  Run it on the chip; it is not
 part of a benchmark run.
@@ -41,17 +41,18 @@ os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"  # the checkout's own cache,
 import numpy as np  # noqa: E402
 
 from smolbench import corpus, harness, traffic  # noqa: E402
-from smolbench.reference import lowp, preproc, resnet as ref_net  # noqa: E402
+from smolbench.reference import lowp, preproc  # noqa: E402
 
 
 TIE_BANDS = (1e-4, 1e-3)  # beside the cell's own ``pixel_tie_levels``
 
 
-def int8_forward(cfg: dict):
-    """``forward(params, net, x)`` of the int8 control, for ``harness.Run``."""
+def int8_forward(reference):
+    """``forward(params, cfg, x)`` of the int8 control, for ``harness.Run``:
+    the family's ``reference`` network with int8 operands."""
 
-    def forward(params, _net, x):
-        return ref_net.forward(params, cfg, x, quant=True)
+    def forward(params, cfg, x):
+        return reference.forward(params, cfg, x, quant=True)
 
     return forward
 
@@ -63,9 +64,10 @@ def control_records(records, items, served, params, cfg: dict, passes: int) -> l
     answered = [r for r in records if r[5] is not None and r[4] is None]
     idx = sorted({r[0] for r in answered})
     x = np.stack([preproc.normalize(lowp.resized(lowp.decode(items[i].variants[served], passes),
-                                                 passes, cfg["input_size"])) for i in idx])
-    out = {i: np.concatenate([lg, px]) for i, lg, px in
-           zip(idx, ref_net.logits(params, cfg, x), harness.pixel_sample(x))}
+                                                 passes, cfg["input_size"], cfg["resize_short"]))
+                  for i in idx])
+    logits = harness.family(cfg["family"]).reference.logits(params, cfg, x)
+    out = {i: np.concatenate([lg, px]) for i, lg, px in zip(idx, logits, harness.pixel_sample(x))}
     return [r[:5] + [out[r[0]]] if r[5] is not None and r[4] is None else list(r) for r in records]
 
 
@@ -87,7 +89,7 @@ def item_separation(items, served, params, cfg: dict) -> float:
     """The smallest, over pairs of corpus items, of the widest gap between
     their reference logits, as a share of the first one's largest logit."""
     x, _lo, _hi = harness.reference_inputs(items, range(len(items)), served, cfg, 0.0)
-    lg = ref_net.logits(params, cfg, x)
+    lg = harness.family(cfg["family"]).reference.logits(params, cfg, x)
     scale = np.abs(lg).max(axis=1)
     best = float("inf")
     for i in range(len(lg)):
@@ -157,7 +159,8 @@ def main(argv=None) -> int:
 
     if args.int8_seeds:
         t = time.perf_counter()
-        ctl = harness.Run(cell, args.int8_seeds[0], args.seconds, False, t, int8_forward(run.cfg))
+        control = int8_forward(run.family.reference)
+        ctl = harness.Run(cell, args.int8_seeds[0], args.seconds, False, t, control)
         ctl.build()
         print(json.dumps({"reading": "control_int8_setup", "setup": ctl.setup_parts}), flush=True)
         for seed in args.int8_seeds:

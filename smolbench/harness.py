@@ -1,8 +1,10 @@
 """One run of one benchmark cell on the served path.
 
 Everything a cell is comes from files found by name: its entry in
-``BENCHMARK.json``, its configuration ``configs/<config>.json``, its traffic
-mix ``traffic/<traffic>.json``, its deployment and limits
+``BENCHMARK.json``, its configuration ``configs/<config>.json``, the model
+family that configuration names ``families/<family>.py`` (the program's
+network, its plain reference and its toy size), its traffic mix
+``traffic/<traffic>.json``, its deployment and limits
 ``workloads/<cell>.json``, and the reader of each per-layer metric,
 ``metrics/<quantity>.py`` (the metric's name up to its first dot).
 
@@ -40,8 +42,7 @@ ROOT = Path(__file__).resolve().parents[1]
 HERE = ROOT / "smolbench"
 CACHE_DIR = ROOT / ".jax_cache"
 
-# off a TPU: the same path with a toy network, corpus and window
-TINY_NET = {"stage_sizes": [1, 1], "width": 8, "num_classes": 16}
+# off a TPU: the same path with a toy corpus and window (and the family's toy network)
 TINY_RUN = {"items": 8, "batch_size": 4, "num_workers": 2, "seconds": 1.0,
             "backlog": 8, "warm_items": 8, "rate_per_s": 8.0, "warm_seconds": 0.5}
 # every 7th row and column of the network input: coprime to the 8x8 block,
@@ -90,17 +91,29 @@ def load_cell(name: str) -> Cell:
     )
 
 
+def _module(kind: str, name: str):
+    """The module ``<kind>/<name>.py``, loaded by path once per process."""
+    key = f"smolbench_{kind}_{name}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, HERE / kind / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]
+
+
+def family(name: str):
+    """The module ``families/<name>.py``: everything the harness knows of one
+    model family."""
+    return _module("families", name)
+
+
 def reader(metric: str):
     """The ``read(ctx)`` function of ``metrics/<quantity>.py``, the quantity
     being the metric's name up to its first dot: ``dispatch_ms.scan`` and
     ``dispatch_ms.open`` split one quantity by the end-to-end metric it moves,
     and share its reader."""
-    quantity = metric.split(".", 1)[0]
-    path = HERE / "metrics" / f"{quantity}.py"
-    spec = importlib.util.spec_from_file_location("smolbench_metric_" + quantity, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric.split(".", 1)[0]).read
 
 
 def emit(*parts, **kv) -> None:
@@ -110,8 +123,8 @@ def emit(*parts, **kv) -> None:
 class Run:
     def __init__(self, cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
                  forward=None):
-        """``forward(params, net, x)`` is the network the runtime serves: the
-        program's ``resnet_forward`` unless a control is put in its place."""
+        """``forward(params, cfg, x)`` is the network the runtime serves: the
+        family's program network unless a control is put in its place."""
         import jax
 
         self.jax = jax
@@ -120,10 +133,11 @@ class Run:
         self.trace = trace
         self.t_start = t_start
         self.forward = forward
+        self.family = family(cell.config["family"])
         devs = jax.devices()
         self.device = {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
         self.on_tpu = self.device["platform"] == "tpu"
-        self.cfg = dict(cell.config) if self.on_tpu else {**cell.config, **TINY_NET}
+        self.cfg = dict(cell.config) if self.on_tpu else {**cell.config, **self.family.TINY}
         self.rt_cfg = dict(cell.workload["runtime"])
         self.traffic = json.loads(json.dumps(cell.traffic))
         self.seconds = seconds
@@ -158,7 +172,6 @@ class Run:
     # ----------------------------------------------------------- set-up
     def build(self) -> None:
         from smolbench import corpus
-        from smolbench.reference import resnet as ref_net
 
         t = time.perf_counter()
         self.items = corpus.build(self.traffic["corpus"], self.seed)
@@ -166,7 +179,7 @@ class Run:
         self.served = self.formats[self.traffic["serve_rendition"]]
         self.setup_parts = {"start_s": t - self.t_start, "corpus_s": time.perf_counter() - t}
         t = time.perf_counter()
-        self.params = self.jax.block_until_ready(ref_net.init_params(self.cfg))
+        self.params = self.jax.block_until_ready(self.family.reference.init_params(self.cfg))
         self.setup_parts["weights_s"] = time.perf_counter() - t
         t = time.perf_counter()
         self.rt = self._runtime()
@@ -180,21 +193,11 @@ class Run:
         self.rt.start_serving()
 
     def _runtime(self):
-        from repro.core.planner import ModelSpec
-        from repro.models.resnet import ResNetConfig, resnet_forward
         from repro.runtime import DeviceCompilerConfig, MemoryConfig, RuntimeConfig, SmolRuntime
 
         c, r = self.cfg, self.rt_cfg
         accuracy = {self.formats[k].key: v for k, v in c["assumed"]["accuracy"].items() if k in self.formats}
-        spec = ModelSpec(c["name"], c["input_size"], c["assumed"]["exec_throughput_items_per_s"], accuracy)
-        net = ResNetConfig(c["name"], c["block"], tuple(c["stage_sizes"]), c["num_classes"], c["width"])
-        params = self.params
-        forward = self.forward or resnet_forward
-
-        def model_fn(x):
-            logits = forward(params, net, x)
-            return self.jax.numpy.concatenate([logits, pixel_sample(x).astype(logits.dtype)], axis=1)
-
+        spec, model_fn = self.family.program(c, self.params, accuracy, self.forward)
         config = RuntimeConfig(
             batch_size=r["batch_size"],
             num_workers=r["num_workers"],
@@ -298,7 +301,7 @@ class Run:
         from smolbench import trace as trace_mod
         from smolbench.kernels import idct, resample
         from smolbench.readers import peaks_for
-        from smolbench.reference import sjpg
+        from smolbench.reference import preproc, sjpg
 
         reduced = None
         if self.trace:
@@ -310,7 +313,8 @@ class Run:
             finally:
                 shutil.rmtree(self.trace_dir, ignore_errors=True)
         geom = sjpg.geometry(self.items[0].variants[self.served])
-        geom["crop"] = max(1, round(self.cfg["input_size"] / 256 * min(geom["height"], geom["width"])))
+        geom["crop"] = preproc.crop_side(geom["height"], geom["width"], self.cfg["input_size"],
+                                         self.cfg["resize_short"])
         geom["size"] = self.cfg["input_size"]
         peaks = peaks_for(self.device["kind"]) if self.on_tpu else None
         ctx = {
@@ -319,7 +323,7 @@ class Run:
             "completed": sum(1 for r in self.client.records.values()
                              if r[3] is not None and self.snap0["t"] <= r[3] <= self.snap1["t"]),
             "latency_ms": getattr(self, "latency_ms", None),
-            "trace": reduced, "config": self.cfg, "geometry": geom,
+            "trace": reduced, "config": self.cfg, "family": self.family, "geometry": geom,
             "peaks": peaks, "batch_size": self.rt_cfg["batch_size"],
         }
         values = {}
@@ -338,7 +342,7 @@ def reference_inputs(items, idx, served, cfg: dict, tie: float) -> tuple:
     float32 arithmetic rounds a near tie of the float64 reference either way."""
     from smolbench.reference import preproc, sjpg
 
-    size = cfg["input_size"]
+    size, short = cfg["input_size"], cfg["resize_short"]
 
     def sample(a):
         return pixel_sample(a.transpose(2, 0, 1)[None])[0]
@@ -348,9 +352,9 @@ def reference_inputs(items, idx, served, cfg: dict, tie: float) -> tuple:
         rgb = sjpg.decode_unrounded(items[i].variants[served])
         near = np.abs(rgb - np.floor(rgb) - 0.5) < tie
         mid = np.clip(np.round(rgb), 0, 255)
-        xs.append(preproc.normalize(preproc.resized(mid, size)))
-        lo = preproc.resized(np.where(near, np.clip(np.floor(rgb), 0, 255), mid), size)
-        hi = preproc.resized(np.where(near, np.clip(np.floor(rgb) + 1, 0, 255), mid), size)
+        xs.append(preproc.normalize(preproc.resized(mid, size, short)))
+        lo = preproc.resized(np.where(near, np.clip(np.floor(rgb), 0, 255), mid), size, short)
+        hi = preproc.resized(np.where(near, np.clip(np.floor(rgb) + 1, 0, 255), mid), size, short)
         los.append(np.clip(np.ceil(sample(lo) - 0.5 - tie), 0, 255))
         his.append(np.clip(np.floor(sample(hi) + 0.5 + tie), 0, 255))
     return np.stack(xs), np.stack(los), np.stack(his)
@@ -384,10 +388,9 @@ def compare(records, items, served, params, cfg: dict, check: dict) -> tuple[dic
       reference's (``reference_inputs``, ties within the cell's
       ``pixel_tie_levels``), which sees the decode and preprocessing layers.
 
+    The reference logits are those of the family that ``cfg`` names.
     Returns the numbers compared with their limits, whether all hold, and
     the failed request count."""
-    from smolbench.reference import resnet as ref_net
-
     recs = list(records)
     missing = sum(1 for r in recs if r[3] is None)
     failed = sum(1 for r in recs if r[3] is not None and r[4] is not None)
@@ -396,7 +399,7 @@ def compare(records, items, served, params, cfg: dict, check: dict) -> tuple[dic
     gap = off = float("inf")
     if idx:
         x, lo, hi = reference_inputs(items, idx, served, cfg, check["pixel_tie_levels"])
-        ref = dict(zip(idx, ref_net.logits(params, cfg, x)))
+        ref = dict(zip(idx, family(cfg["family"]).reference.logits(params, cfg, x)))
         nc = cfg["num_classes"]
         gaps = []
         for r in answered:

@@ -5,9 +5,9 @@ A reader gets the run's context: two snapshots of the runtime, ``s0`` and
 dispatches of each bucket program), the answers released between them
 (``completed``), the percentiles of the latencies of the requests due in the
 window (``latency_ms``, due time to release), the reduced trace (``trace``,
-None without one), the served
-geometry, the configuration and the chip's peaks.  A reader that finds
-nothing to read returns None, and the metric is left out of the line.
+None without one), the served geometry, the configuration, its model family
+and the chip's peaks.  A reader that finds nothing to read returns None, and
+the metric is left out of the line.
 """
 
 from __future__ import annotations
@@ -16,7 +16,6 @@ import json
 from pathlib import Path
 
 from smolbench.kernels import idct, resample
-from smolbench.reference import resnet as ref_net
 
 KERNELS = {"idct": idct, "resample": resample}
 PEAKS = Path(__file__).with_name("peaks.json")
@@ -105,9 +104,9 @@ def roofline_pct(ctx, kernel: str):
 
 
 def item_flops(ctx) -> float:
-    """Operations per served item: the network's convolutions and head, and
-    the two preprocessing kernels' algorithmic work."""
-    flops = 2.0 * ref_net.conv_macs(ctx["config"], ctx["geometry"]["size"])
+    """Operations per served item: the network's, as its family's reference
+    counts them, and the two preprocessing kernels' algorithmic work."""
+    flops = float(ctx["family"].reference.item_flops(ctx["config"], ctx["geometry"]["size"]))
     for k in KERNELS.values():
         flops += k.count(ctx["geometry"], 1)[0]
     return flops

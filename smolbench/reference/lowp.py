@@ -52,7 +52,7 @@ def decode(data: bytes, passes: int) -> np.ndarray:
     return sjpg.to_uint8(sjpg.planes_to_rgb(geom, grids, blocks, convert))
 
 
-def resized(rgb: np.ndarray, passes: int, size: int = 224, resize_short: int = 256) -> np.ndarray:
+def resized(rgb: np.ndarray, passes: int, size: int, resize_short: int) -> np.ndarray:
     """(H, W, 3) uint8 -> (size, size, 3) crop and resize at ``passes``,
     before the re-quantization to uint8."""
     x = preproc.crop(rgb, size, resize_short)

@@ -1,11 +1,13 @@
 """Plain reference of the served preprocessing, in float64 on the host.
 
-The published chain is ResizeShortSide(256) -> CenterCrop(224) -> ToFloat
--> Normalize(ImageNet mean and std) -> CHW.  The system serves it reordered
-(paper §6.2, rule R3): a centre crop of ``224/256`` of the short side, then a
-bilinear resize (half-pixel centres) to 224x224 that re-quantizes to uint8,
-then the affine.  The reference computes that served order, which is the
-one departure from the published chain; at full resolution the crop is
+The published chain is ResizeShortSide(resize_short) -> CenterCrop(size)
+-> ToFloat -> Normalize(ImageNet mean and std) -> CHW, both sizes from the
+configuration (``resize_short`` and ``input_size``; 256 and 224 for
+ResNet).  The system serves it reordered (paper §6.2, rule R3): a centre
+crop of ``size / resize_short`` of the short side, then a bilinear resize
+(half-pixel centres) to ``size`` x ``size`` that re-quantizes to uint8, then
+the affine.  The reference computes that served order, which is the one
+departure from the published chain; for ResNet on a 256-px item the crop is
 224 px and the resize an identity.
 """
 
@@ -23,10 +25,16 @@ def _coords(n_in: int, n_out: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     return i0, np.minimum(i0 + 1, n_in - 1), s - i0
 
 
-def crop(rgb: np.ndarray, size: int = 224, resize_short: int = 256) -> np.ndarray:
+def crop_side(h: int, w: int, size: int, resize_short: int) -> int:
+    """Side of the centre crop of an ``h`` x ``w`` image: ``size /
+    resize_short`` of its short side."""
+    return max(1, round(size / resize_short * min(h, w)))
+
+
+def crop(rgb: np.ndarray, size: int, resize_short: int) -> np.ndarray:
     """The centre crop of ``size / resize_short`` of the short side, float64."""
     h, w = rgb.shape[:2]
-    s = max(1, round(size / resize_short * min(h, w)))
+    s = crop_side(h, w, size, resize_short)
     t, l = (h - s) // 2, (w - s) // 2
     return rgb[t : t + s, l : l + s].astype(np.float64)
 
@@ -47,7 +55,7 @@ def normalize(out: np.ndarray) -> np.ndarray:
     return ((out / 255.0 - MEAN) / STD).transpose(2, 0, 1).astype(np.float32)
 
 
-def resized(rgb: np.ndarray, size: int = 224, resize_short: int = 256) -> np.ndarray:
+def resized(rgb: np.ndarray, size: int, resize_short: int) -> np.ndarray:
     """(H, W, 3) uint8 -> (size, size, 3) float64 crop and resize, before
     the re-quantization to uint8."""
     x = crop(rgb, size, resize_short)
@@ -56,11 +64,6 @@ def resized(rgb: np.ndarray, size: int = 224, resize_short: int = 256) -> np.nda
     x0, x1, wx = _coords(s, size)
     rows = x[y0] * (1 - wy)[:, None, None] + x[y1] * wy[:, None, None]
     return rows[:, x0] * (1 - wx)[None, :, None] + rows[:, x1] * wx[None, :, None]
-
-
-def preprocess(rgb: np.ndarray, size: int = 224, resize_short: int = 256) -> np.ndarray:
-    """(H, W, 3) uint8 -> (3, size, size) float32 network input."""
-    return normalize(resized(rgb, size, resize_short))
 
 
 def levels(x: np.ndarray, channel_axis: int) -> np.ndarray:

@@ -173,6 +173,12 @@ def logits(params, cfg: dict, x: np.ndarray, quant: bool = False, block: int = 3
     return np.concatenate(out)
 
 
+def item_flops(cfg: dict, size: int) -> int:
+    """Operations of one forward pass at ``size`` x ``size`` input: two per
+    multiply-accumulate of ``conv_macs``."""
+    return 2 * conv_macs(cfg, size)
+
+
 def conv_macs(cfg: dict, size: int) -> int:
     """Multiply-accumulates of one forward pass at ``size`` x ``size`` input:
     every convolution and the head (pooling and normalization excluded, as

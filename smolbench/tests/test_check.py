@@ -90,7 +90,7 @@ def test_altered_answer_is_not_correct(jax_cpu, cell, fault, monkeypatch):
 @pytest.mark.parametrize("cell", CELLS)
 def test_int8_control_is_not_correct(jax_cpu, cell):
     c = harness.load_cell(cell)
-    _run, result = _execute(cell, calibrate.int8_forward({**c.config, **harness.TINY_NET}))
+    _run, result = _execute(cell, calibrate.int8_forward(harness.family(c.config["family"]).reference))
     assert not result["correct"]
     assert result["limits"]["logit_gap"]["value"] > result["limits"]["logit_gap"]["limit"]
 
