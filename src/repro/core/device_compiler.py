@@ -246,6 +246,69 @@ def _place(batch: Any, device: Any):
     return jax.device_put(batch, device)
 
 
+# ------------------------------------------------------------- model entries
+@dataclasses.dataclass(frozen=True, eq=False)
+class ModelEntry:
+    """A model whose weights are program arguments: ``fn(params, x)``.
+
+    A plain ``model_fn(x)`` closure is traced with its weights as
+    compile-time constants of every program that calls it.  An entry's
+    programs take ``params`` as their first argument instead, so one device
+    copy per replica serves every bucket program there and no program holds
+    the weights.  Calling the entry as ``entry(x)`` runs ``fn`` on its own
+    ``params``.  Entries compare and hash by identity, which is part of the
+    program cache key.
+    """
+
+    fn: Callable[[Any, Any], Any]
+    params: Any
+
+    def __call__(self, x):
+        return self.fn(self.params, x)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.params))
+
+
+def place_params(params: Any, device: Any) -> Any:
+    """Commit a weight pytree to a program's device placement: ``None``
+    keeps the process default, a ``jax.Device`` copies there, a Sharding
+    over a replica group replicates the weights on every member."""
+    if device is None:
+        return jax.device_put(params)
+    if hasattr(device, "device_set"):
+        device = jax.sharding.NamedSharding(device.mesh, jax.sharding.PartitionSpec())
+    return jax.device_put(params, device)
+
+
+def _model_key(model_fn: Callable, model_key: str) -> Any:
+    """Program-cache identity of the model: its name, and an entry's own
+    identity besides (two entries of one name hold different weights)."""
+    if isinstance(model_fn, ModelEntry):
+        return (model_key, "args", id(model_fn))
+    return model_key
+
+
+def _with_model(stage: Callable, model_fn: Callable, params: Any) -> tuple[Callable, Any]:
+    """``(raw, params)``: the program body ``stage`` then the model, as
+    ``raw(batch)`` over a closure, or as ``raw(params, batch)`` over a
+    :class:`ModelEntry`, ``params`` being its placed weights (default: the
+    entry's own)."""
+    if not isinstance(model_fn, ModelEntry):
+
+        def raw(batch):
+            return model_fn(stage(batch))
+
+        return raw, None
+    fn = model_fn.fn
+
+    def raw(weights, batch):  # the same name either way: the program is jit_raw
+        return fn(weights, stage(batch))
+
+    return raw, model_fn.params if params is None else params
+
+
 # ------------------------------------------------------------------- lowering
 @dataclasses.dataclass(frozen=True)
 class Lowering:
@@ -468,10 +531,18 @@ class DevicePreprocProgram:
     # replica group (sharded-model mode) — staged batches are committed
     # there before dispatch, so XLA compiles/partitions per placement
     device: Any = None
+    # a ModelEntry's weights on this program's placement, passed as the
+    # program's first argument (None: the model is a closure over constants)
+    params: Any = None
 
     @property
     def dispatches_per_batch(self) -> int:
         return 1  # the whole suffix + DNN is one XLA program
+
+    def _args(self, batch) -> tuple:
+        if self.params is None:
+            return (batch,)
+        return (self.params, batch)
 
     def __call__(self, batch):
         if self.dispatch_count == 0:
@@ -480,7 +551,7 @@ class DevicePreprocProgram:
 
             t0 = time.perf_counter()
             with span("smol.compile", batch=self.batch_size):
-                out = self.fn(_place(batch, self.device))
+                out = self.fn(*self._args(_place(batch, self.device)))
                 jax.block_until_ready(out)
             self.first_dispatch_seconds = time.perf_counter() - t0
             # counted only once it ran: a compile that raised leaves the
@@ -490,18 +561,19 @@ class DevicePreprocProgram:
                 self.compile_listener(self, self.first_dispatch_seconds)
             return out
         self.dispatch_count += 1
-        return self.fn(_place(batch, self.device))
+        return self.fn(*self._args(_place(batch, self.device)))
 
     def lower(self, batch):
         """Lower (without executing) — for HLO inspection tooling."""
-        return self.fn.lower(batch)
+        return self.fn.lower(*self._args(batch))
 
 
-def _jit(raw: Callable, donate: bool) -> Callable:
+def _jit(raw: Callable, donate: bool, params: Any = None) -> Callable:
     # donation lets XLA reuse the staged batch's device allocation; the CPU
-    # backend can't honor it and warns, so only donate on accelerators
+    # backend can't honor it and warns, so only donate on accelerators.
+    # The batch is the last argument; argument weights are never donated.
     if donate and jax.default_backend() != "cpu":
-        return jax.jit(raw, donate_argnums=(0,))
+        return jax.jit(raw, donate_argnums=(0 if params is None else 1,))
     return jax.jit(raw)
 
 
@@ -678,6 +750,7 @@ def compile_device_program(
     model_key: str = "",
     cache: MutableMapping[tuple, "DevicePreprocProgram"] | None = None,
     device: Any = None,
+    params: Any = None,
 ) -> DevicePreprocProgram:
     """Lower ``device_ops`` + ``model_fn`` into one jitted device program.
 
@@ -689,15 +762,18 @@ def compile_device_program(
     moves free when the split returns to a previously-seen point.
     ``device`` pins the program to one replica's accelerator (or, given a
     Sharding, spans a replica group) — each placement is its own cache
-    entry, so a mesh gets one program instance per replica.
+    entry, so a mesh gets one program instance per replica.  A
+    :class:`ModelEntry` ``model_fn`` takes its weights as the program's first
+    argument: ``params``, its weights placed on ``device``, or by default
+    the entry's own.
     """
     if backend not in ("fused", "reference"):
         raise ValueError(f"device_backend must be 'fused' or 'reference', got {backend!r}")
     impl = resolve_impl(impl) if backend == "fused" else "chain"
     interpret = resolve_interpret(interpret)
     key = program_cache_key(
-        device_ops, in_meta, batch_size, backend, impl, model_key, interpret, donate,
-        device,
+        device_ops, in_meta, batch_size, backend, impl, _model_key(model_fn, model_key),
+        interpret, donate, device,
     )
     if cache is not None and key in cache:
         return cache[key]
@@ -713,14 +789,11 @@ def compile_device_program(
         stages = tuple(op.name for op in device_ops)
         out_meta = P.chain_out_meta(list(device_ops), in_meta)
     else:
-        stage, impl, fused, stages, out_meta = None, "model-only", False, (), in_meta
-
-    def raw(batch):
-        x = stage(batch) if stage is not None else jnp.asarray(batch)
-        return model_fn(x)
+        stage, impl, fused, stages, out_meta = jnp.asarray, "model-only", False, (), in_meta
+    raw, params = _with_model(stage, model_fn, params)
 
     program = DevicePreprocProgram(
-        fn=_jit(raw, donate),
+        fn=_jit(raw, donate, params),
         backend=backend,
         impl=impl,
         fused=fused,
@@ -729,6 +802,7 @@ def compile_device_program(
         in_meta=in_meta,
         out_meta=out_meta,
         device=device,
+        params=params,
         batch_size=batch_size,
         interpret=interpret,
         build_seconds=time.perf_counter() - t_build,
@@ -759,6 +833,7 @@ def compile_coeff_program(
     model_key: str = "",
     cache: MutableMapping[tuple, "DevicePreprocProgram"] | None = None,
     device: Any = None,
+    params: Any = None,
 ) -> DevicePreprocProgram:
     """Split-decode program: quantized DCT coefficients in, predictions out.
 
@@ -774,7 +849,8 @@ def compile_coeff_program(
     ``factor > 1`` decodes straight to reduced resolution (paper §6.4 /
     libjpeg draft): the pixel grid entering the preprocessing chain is
     ``(ceil(h/factor), ceil(w/factor))``, so a plan that immediately
-    downsamples never pays for full-resolution pixels at all.
+    downsamples never pays for full-resolution pixels at all.  A
+    :class:`ModelEntry` takes ``params`` as in :func:`compile_device_program`.
     """
     from repro.preprocessing import dct as dct_np
     from repro.preprocessing import jpeg as jpeg_mod
@@ -800,8 +876,8 @@ def compile_coeff_program(
         ("CoeffDecode", header.quality, n_br, n_bc, header.height, header.width,
          subsample, factor, layout),
         program_cache_key(
-            device_ops, pixel_meta, batch_size, "fused", impl, model_key, interpret,
-            donate, device,
+            device_ops, pixel_meta, batch_size, "fused", impl, _model_key(model_fn, model_key),
+            interpret, donate, device,
         ),
     )
     if cache is not None and key in cache:
@@ -830,7 +906,7 @@ def compile_coeff_program(
     n_luma = n_br * n_bc
     n_chroma = cbr * cbc
 
-    def raw(batch):  # one staged int16 zigzag-coefficient tensor per item
+    def decode(batch):  # one staged int16 zigzag-coefficient tensor per item
         n = batch.shape[0]
         zz = jnp.asarray(batch)
         if layout == "packed":  # (N, n_luma + 2*n_chroma, 64)
@@ -870,14 +946,16 @@ def compile_coeff_program(
             precision=jax.lax.Precision.HIGHEST,
         )
         rgb = jnp.clip(jnp.round(rgb), 0.0, 255.0)  # the decoded uint8 pixel grid
-        return model_fn(preproc(rgb))
+        return preproc(rgb)
+
+    raw, params = _with_model(decode, model_fn, params)
 
     idct_stage = "dequant_idct[mxu]" if point == 8 else f"dequant_idct[mxu]/{point}pt"
     decode_stages = ("unzigzag", idct_stage, "unblockify")
     if subsample:
         decode_stages += ("chroma_upsample[2x2]",)
     program = DevicePreprocProgram(
-        fn=_jit(raw, donate),
+        fn=_jit(raw, donate, params),
         backend="fused",
         impl=impl,
         fused=fused,
@@ -888,6 +966,7 @@ def compile_coeff_program(
         coeff_factor=factor,
         coeff_layout=layout,
         device=device,
+        params=params,
         batch_size=batch_size,
         interpret=interpret,
         build_seconds=time.perf_counter() - t_build,

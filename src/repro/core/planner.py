@@ -36,6 +36,9 @@ class ModelSpec:
     exec_throughput: float  # measured items/sec on synthetic batches
     accuracy_by_format: dict[str, float]  # format.key -> validation accuracy
     pass_fraction: float = 1.0  # for cascade members: fraction reaching it
+    # short side the chain resizes to before the centre crop; None derives
+    # it from input_size as ResNet does (256/224)
+    resize_short: int | None = None
 
 
 @dataclasses.dataclass
@@ -59,11 +62,17 @@ class QueryPlan:
         return f"QueryPlan({self.key}: {e.throughput:.0f} im/s, acc={e.accuracy:.4f})"
 
 
-def standard_chain(input_size: int) -> list[P.PreprocOp]:
-    """The ResNet-style preprocessing chain (paper §2) for a target input."""
-    resize_short = round(input_size * 256 / 224)
+def short_side(input_size: int, resize_short: int | None = None) -> int:
+    """The chain's short side: ``resize_short`` where the model states one,
+    else ResNet's ratio, 256/224 of the input."""
+    return round(input_size * 256 / 224) if resize_short is None else resize_short
+
+
+def standard_chain(input_size: int, resize_short: int | None = None) -> list[P.PreprocOp]:
+    """The ResNet-style preprocessing chain (paper §2) for a target input:
+    resize the short side to :func:`short_side`, centre-crop ``input_size``."""
     return [
-        P.ResizeShortSide(resize_short),
+        P.ResizeShortSide(short_side(input_size, resize_short)),
         P.CenterCrop(input_size),
         P.ToFloat(),
         P.Normalize(),
@@ -117,8 +126,10 @@ def measure_entropy_decode_time(
     return (time.perf_counter() - t0) / n
 
 
-def central_roi(input_size: int, resize_short: int):
-    """ROI covering the central crop in original coordinates (Algorithm 1)."""
+def central_roi(input_size: int, resize_short: int | None = None):
+    """ROI covering the central crop in original coordinates (Algorithm 1);
+    ``resize_short`` as :func:`short_side` takes it."""
+    resize_short = short_side(input_size, resize_short)
 
     def fn(full: tuple[int, int, int, int]):
         _, _, h, w = full
@@ -296,7 +307,7 @@ class Planner:
         acc = model.accuracy_by_format.get(fmt.key)
         if acc is None:
             return None  # model was not trained/evaluated for this format
-        chain = standard_chain(model.input_size)
+        chain = standard_chain(model.input_size, model.resize_short)
         dag_plan = dag_mod.optimize(chain, self.decoded_meta(fmt))
         return self._place_and_estimate(
             model, fmt, dag_plan, acc, self.decode_time(fmt), 1.0 / model.exec_throughput

@@ -481,6 +481,11 @@ class SmolRuntime:
         # beyond program_cache_entries are LRU-evicted (an active tenant's
         # program is re-looked-up on every rebind and stays resident).
         self._device_programs = ProgramCache(self.config.program_cache_entries)
+        # argument weights of each ModelEntry, placed once per replica
+        # target and shared by every bucket program there:
+        # (id(entry), device_cache_key(target)) -> (placed, nbytes); the
+        # entries live in model_fns for the runtime's life, so ids are stable
+        self._weights: dict[tuple, tuple[Any, int]] = {}
         # measured per-dispatch launch overhead (lazily filled when the
         # config leaves device_dispatch_overhead_s at None)
         self._measured_dispatch_s: float | None = None
@@ -669,6 +674,19 @@ class SmolRuntime:
         return self.planner().pareto()
 
     # ------------------------------------------------------------- compiling
+    def _weights_for(self, model_fn: Callable, device: Any) -> Any:
+        """A :class:`ModelEntry`'s weights on one replica target, placed on
+        first use under a ``smol.weights`` span; None for a closure."""
+        if not isinstance(model_fn, device_compiler.ModelEntry):
+            return None
+        key = (id(model_fn), device_compiler.device_cache_key(device))
+        if key not in self._weights:
+            nbytes = model_fn.nbytes
+            with self.telemetry.span("smol.weights", bytes=nbytes, device=self._target_label(device)):
+                placed = jax.block_until_ready(device_compiler.place_params(model_fn.params, device))
+            self._weights[key] = (placed, nbytes)
+        return self._weights[key][0]
+
     def _coeff_stage_fns(
         self,
         plan: QueryPlan,
@@ -691,11 +709,12 @@ class SmolRuntime:
 
         header = jpeg_mod.peek_header(self.calibration[0].variants[fmt])
         chain = list(plan.dag_plan.ops)
+        model_fn = self.model_fns[plan.model.name]
         try:
             program = device_compiler.compile_coeff_program(
                 header,
                 chain,
-                self.model_fns[plan.model.name],
+                model_fn,
                 batch_size or self.config.batch_size,
                 factor=coeff.factor,
                 layout=coeff.layout,
@@ -703,6 +722,7 @@ class SmolRuntime:
                 model_key=plan.model.name,
                 cache=self._device_programs,
                 device=device,
+                params=self._weights_for(model_fn, device),
             )
         except ValueError:
             return None
@@ -839,6 +859,7 @@ class SmolRuntime:
             model_key=plan.model.name,
             cache=self._device_programs,
             device=device,
+            params=self._weights_for(model_fn, device),
         )
         program.compile_listener = self._on_program_compiled
         return host_fn, program, out_shape, out_dtype
@@ -1974,6 +1995,9 @@ class SmolRuntime:
             )
         digest = self.telemetry.summary()
         latency = LatencySection(stages=digest["stages"], tenants=digest["tenants"])
+        per_target: dict[Any, int] = {}
+        for (_entry, target), (_placed, nbytes) in self._weights.items():
+            per_target[target] = per_target.get(target, 0) + nbytes
         return RuntimeStats(
             num_workers=self._num_workers,
             measured_dispatch_overhead_s=self._measured_dispatch_s,
@@ -1990,6 +2014,7 @@ class SmolRuntime:
             programs_compiled_post_warmup=self._programs_compiled_post_warmup,
             program_compile_seconds_total=self._program_compile_seconds,
             warmup_failures=self._warm_failures,
+            weight_bytes=max(per_target.values(), default=0),
         )
 
     # ------------------------------------------------------------- telemetry

@@ -189,6 +189,9 @@ class RuntimeStats:
     # background warmup compiles that raised, over the runtime's life
     # (wait_warm() raises each one once)
     warmup_failures: int = 0
+    # bytes of ModelEntry argument weights resident on each replica target
+    # (one copy per target, shared by its bucket programs; 0 for closures)
+    weight_bytes: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         """JSON-safe mapping (stable wire format for the schema version)."""

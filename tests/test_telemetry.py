@@ -447,3 +447,42 @@ def test_no_profiler_session_records_no_span_and_no_ring(monkeypatch):
     assert len(done) == 16
     assert made == [] and tel.ring_allocations == 0
     assert sched.stats.launch_seconds > 0 and sched.stats.host_items == 16
+
+
+def test_argument_weights_are_placed_once_per_replica_under_a_span(tmp_path):
+    """A ``ModelEntry``'s weights are placed once on the replica, under one
+    ``smol.weights`` span carrying their bytes, however many bucket programs
+    compile there; ``stats().weight_bytes`` counts that one copy."""
+    from repro.core.device_compiler import ModelEntry
+
+    fmt = ImageFormat("jpeg", None, 95)
+    rng = np.random.default_rng(9)
+    corpus = [StoredImage.from_array(smooth_image(rng, 40, 40), [fmt]) for _ in range(3)]
+    params = {"w": np.asarray(jax.random.normal(jax.random.PRNGKey(1), (3 * 16 * 16, 5)) * 0.02),
+              "b": np.arange(5, dtype=np.float32)}
+    entry = ModelEntry(lambda p, x: x.reshape(x.shape[0], -1) @ p["w"] + p["b"], params)
+    rt = SmolRuntime(
+        [ModelSpec("m", 16, exec_throughput=50_000.0, accuracy_by_format={fmt.key: 0.9})],
+        [fmt],
+        {"m": entry},
+        calibration=corpus[:2],
+        config=RuntimeConfig(batch_size=4, num_workers=1, warmup="full",
+                             device=DeviceCompilerConfig(backend="fused", split_decode="full")),
+        decode_time=lambda fmt: 1e-4,
+    )
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        with jax.profiler.TraceAnnotation(WINDOW):
+            compiled = rt.compile()
+            assert rt.wait_warm(timeout=120.0)
+    assert len(compiled.program_sets[0].programs) == 3  # buckets 1, 2, 4
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    events = [e for plane in jax.profiler.ProfileData.from_file(path).planes if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events]
+    (window,) = [(e.start_ns, e.end_ns) for e in events if e.name == WINDOW]
+    (placed,) = [e for e in events if e.name == "smol.weights"]
+    assert window[0] <= placed.start_ns and placed.end_ns <= window[1]
+    nbytes = params["w"].nbytes + params["b"].nbytes
+    assert dict(placed.stats) == {"bytes": nbytes, "device": "default"}
+    assert rt.stats().weight_bytes == entry.nbytes == nbytes

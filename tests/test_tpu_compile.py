@@ -128,3 +128,41 @@ def test_coefficient_program_compiles_with_both_kernels(one_chip):
     assert _custom_calls(compiled) == 3
     # inside the whole program the kernels keep the op names a trace reads
     assert _kernel_names(compiled) == {"dequant_idct_tiles", "fused_resize_normalize_planar"}
+
+
+def test_vit_coefficient_program_takes_its_weights_as_arguments(one_chip):
+    # the ViT's split-decode program at 384 px: a 256->384 upsample in the
+    # resample kernel, and the weights as arguments rather than constants
+    from repro.models.vit import ViTConfig, vit_forward
+
+    net = ViTConfig(384, 16, 2, 64, 4, 128, 10)
+    d, m, t = net.hidden, net.mlp, net.tokens
+
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def dense(n_in, n_out, *stack):
+        return {"w": leaf(*stack, n_in, n_out), "b": leaf(*stack, n_out)}
+
+    def ln(*stack):
+        return {"scale": leaf(*stack, d), "bias": leaf(*stack, d)}
+
+    layers = net.layers
+    params = {"patch": {"w": leaf(16, 16, 3, d), "b": leaf(d)}, "cls": leaf(d), "pos": leaf(t, d),
+              "blocks": {"ln1": ln(layers), "qkv": dense(d, 3 * d, layers), "proj": dense(d, d, layers),
+                         "ln2": ln(layers), "fc1": dense(d, m, layers), "fc2": dense(m, d, layers)},
+              "ln": ln(), "head": dense(d, net.num_classes)}
+    img = np.random.default_rng(0).integers(0, 256, (256, 256, 3), dtype=np.uint8)
+    header = jpeg.peek_header(jpeg.encode(img, quality=95, subsample=True))
+    ops = dag_mod.optimize(standard_chain(384, 384), TensorMeta((256, 256, 3), "uint8", "HWC")).ops
+    entry = DC.ModelEntry(lambda p, x: vit_forward(p, net, x), params)
+    prog = DC.compile_coeff_program(header, ops, entry, 4, layout="packed", impl="pallas", interpret=False)
+    batch = jax.ShapeDtypeStruct((4, *prog.in_meta.shape), jnp.int16, sharding=one_chip)
+    compiled = prog.lower(batch).compile()
+    assert _custom_calls(compiled) == 3
+    # every weight is a parameter of the entry computation, beside the batch
+    leaves = jax.tree_util.tree_leaves(params)
+    entry_block = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    assert entry_block.count(" parameter(") == len(leaves) + 1
+    weight_bytes = sum(4 * int(np.prod(s.shape)) for s in leaves)
+    assert compiled.memory_analysis().argument_size_in_bytes >= weight_bytes + 2 * int(np.prod(batch.shape))
