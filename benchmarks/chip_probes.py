@@ -15,6 +15,10 @@ dnn            ResNet-50 logits at the default precision, and with bf16
 compile_cache  one ResNet-50 split-decode bucket program lowered and compiled
                twice with the in-memory caches cleared between, counting the
                persistent cache's events and the growth of its directory.
+vit_scopes     ViT-L/16 at 384 px alone (``vit_forward``, its weights as
+               arguments) at batch 32: wall ms per batch, and the device time
+               of a traced batch split by ``jax.named_scope`` (embed,
+               attention, mlp, head) and by op.
 """
 
 from __future__ import annotations
@@ -22,10 +26,13 @@ from __future__ import annotations
 import collections
 import json
 import os
+import re
 import sys
+import tempfile
 import time
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src"))
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
 import jax
 import jax.numpy as jnp
@@ -183,7 +190,65 @@ def probe_compile_cache(batch: int = 32) -> None:
              cache_events=dict(events), dir_bytes_added=_dir_bytes(cache_dir) - before)
 
 
-PROBES = {"precision": probe_precision, "dnn": probe_dnn, "compile_cache": probe_compile_cache}
+# ------------------------------------------------------------ vit scopes
+SCOPES = ("embed", "attention", "mlp", "head")
+_INSTR = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = .*?metadata=\{op_name="([^"]*)"')
+
+
+def _scope_of(compiled) -> dict[str, str]:
+    """Instruction name -> the named scope its op came from ("other" if none)."""
+    out = {}
+    for line in compiled.as_text().splitlines():
+        m = _INSTR.match(line)
+        if m:
+            parts = m.group(2).split("/")
+            out[m.group(1)] = next((s for s in SCOPES if s in parts), "other")
+    return out
+
+
+def probe_vit_scopes(batch: int = 32, timed: int = 20, traced: int = 3) -> None:
+    if jax.default_backend() != "tpu":
+        raise SystemExit("vit_scopes times ViT-L/16 on a TPU; elsewhere its attention kernel is interpreted")
+    from repro.models.vit import ViTConfig, vit_forward
+    from smolbench import trace
+    from smolbench.reference import vit as reference
+
+    with open(os.path.join(ROOT, "smolbench", "configs", "vitl16_384.json")) as f:
+        cfg = json.load(f)
+    net = ViTConfig(cfg["input_size"], cfg["patch"], cfg["layers"], cfg["hidden"], cfg["heads"],
+                    cfg["mlp"], cfg["num_classes"], cfg["layer_norm_eps"])
+    params = reference.init_params(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (batch, 3, net.image_size, net.image_size))
+    compiled = jax.jit(lambda p, x: vit_forward(p, net, x)).lower(params, x).compile()
+    compiled(params, x).block_until_ready()
+    walls = []
+    for _ in range(timed):
+        t0 = time.perf_counter()
+        compiled(params, x).block_until_ready()
+        walls.append(time.perf_counter() - t0)
+    with tempfile.TemporaryDirectory(prefix="vit-scopes-") as log_dir:
+        with jax.profiler.trace(log_dir):
+            for _ in range(traced):
+                compiled(params, x).block_until_ready()
+        events = trace.load(trace.find_xplane(log_dir))["device"]
+    scope_of = _scope_of(compiled)
+    by_scope: collections.Counter = collections.Counter()
+    by_op: collections.Counter = collections.Counter()
+    for name, start, end in events[min(events)]:
+        if trace.op_name(name) in ("while", "conditional", "call"):
+            continue  # a loop's event spans its body's, which are counted
+        instr = name.split(" = ", 1)[0].strip().lstrip("%")
+        by_scope[scope_of.get(instr, "other")] += (end - start) / 1e6 / traced
+        by_op[instr] += (end - start) / 1e6 / traced
+    walls.sort()
+    emit(probe="vit_scopes", batch=batch, wall_ms_median=1e3 * walls[len(walls) // 2],
+         wall_ms_min=1e3 * walls[0], device_ms_per_batch=sum(by_scope.values()),
+         scope_ms_per_batch=dict(by_scope), top_ops_ms_per_batch=dict(by_op.most_common(12)),
+         cost_analysis_bytes=compiled.cost_analysis().get("bytes accessed"))
+
+
+PROBES = {"precision": probe_precision, "dnn": probe_dnn, "compile_cache": probe_compile_cache,
+          "vit_scopes": probe_vit_scopes}
 
 
 def main(argv=None) -> int:

@@ -14,6 +14,8 @@ Kernels target TPU (MXU-aligned tiles).  The wrappers take
                     (the device half of SMOL's split JPEG decode)
 * fused_preproc   — resize-as-matmul + normalize + channel layout in one
                     VMEM pass (the DAG optimizer's fusion product, §6.2)
+* vit_attention   — whole-row non-causal self-attention of a ViT block,
+                    reading q, k, v from the packed qkv; scores stay in VMEM
 * flash_attention — blockwise streaming attention (causal / sliding window)
 * decode_attention— flash-decoding for single-token serve steps over long
                     KV caches

@@ -13,7 +13,14 @@ and the class token through a linear head.
   precision, as the ResNets do.
 * GELU is the tanh form of the original JAX release (``flax.linen.gelu``'s
   default); PyTorch ports use the erf form.
-* Attention is plain XLA (``einsum`` and softmax in float32).
+* Attention is the ``vit_attention`` Pallas kernel (``kernels/vit_attention``):
+  it reads q, k and v straight from the packed qkv projection and keeps the
+  scores and probabilities in VMEM.  qkv is fed to it in the dtype XLA would
+  round the attention's matmul operands to (``matmul_operand_dtype``):
+  bfloat16 on a TPU at the default precision, float32 where the caller
+  raised the precision and on other backends.  ``platform`` names the
+  target where it is not the default backend (a program compiled for a
+  described chip).
 * ``jax.named_scope`` names the embedding, attention, MLP and head, so a
   profile attributes them.
 
@@ -31,6 +38,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.mode import matmul_operand_dtype, resolve_interpret
+from repro.kernels.vit_attention import vit_attention
 from repro.models.layers import layernorm
 
 
@@ -51,16 +60,11 @@ class ViTConfig:
         return (self.image_size // self.patch) ** 2 + 1
 
 
-def _block(cfg: ViTConfig, x, p):
-    n, t, d = x.shape
-    hd = d // cfg.heads
+def _block(cfg: ViTConfig, x, p, platform: str | None = None):
     with jax.named_scope("attention"):
         h = layernorm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.eps)
-        qkv = (h @ p["qkv"]["w"] + p["qkv"]["b"]).reshape(n, t, 3, cfg.heads, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        scores = jnp.einsum("nqhd,nkhd->nhqk", q, k) * hd**-0.5
-        a = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("nhqk,nkhd->nqhd", a, v).reshape(n, t, d)
+        qkv = (h @ p["qkv"]["w"] + p["qkv"]["b"]).astype(matmul_operand_dtype(platform))
+        o = vit_attention(qkv, cfg.heads, interpret=resolve_interpret(platform=platform))
         x = x + o @ p["proj"]["w"] + p["proj"]["b"]
     with jax.named_scope("mlp"):
         h = layernorm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.eps)
@@ -68,7 +72,7 @@ def _block(cfg: ViTConfig, x, p):
         return x + h @ p["fc2"]["w"] + p["fc2"]["b"]
 
 
-def vit_forward(params, cfg: ViTConfig, x):
+def vit_forward(params, cfg: ViTConfig, x, platform: str | None = None):
     """(N, 3, H, W) float32 images -> (N, num_classes) logits."""
     n = x.shape[0]
     with jax.named_scope("embed"):
@@ -81,7 +85,7 @@ def vit_forward(params, cfg: ViTConfig, x):
         x = jnp.concatenate([cls, tokens], axis=1) + params["pos"]
 
     def body(carry, p):
-        return _block(cfg, carry, p), None
+        return _block(cfg, carry, p, platform), None
 
     x, _ = jax.lax.scan(body, x, params["blocks"])
     with jax.named_scope("head"):

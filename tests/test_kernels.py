@@ -15,7 +15,8 @@ from repro.kernels.fused_preproc.ops import fused_resize_affine, fused_resize_no
 from repro.kernels.fused_preproc.ref import fused_resize_normalize_ref
 from repro.kernels.idct.ops import dequant_idct
 from repro.kernels.idct.ref import dequant_idct_ref
-from repro.kernels.mode import resolve_interpret
+from repro.kernels.mode import matmul_operand_dtype, resolve_interpret
+from repro.kernels.vit_attention import vit_attention
 from repro.preprocessing import dct
 
 RNG = np.random.default_rng(0)
@@ -137,7 +138,7 @@ def test_decode_attention_sweep(b, h, kvh, s, d, window):
 
 @pytest.mark.parametrize(
     "wrapper",
-    [dequant_idct, fused_resize_affine, fused_resize_normalize, flash_attention, decode_attention],
+    [dequant_idct, fused_resize_affine, fused_resize_normalize, flash_attention, decode_attention, vit_attention],
 )
 def test_kernel_wrappers_follow_the_backend_rule(wrapper):
     # no wrapper hard-codes interpret mode: None resolves to compiled on a
@@ -145,3 +146,19 @@ def test_kernel_wrappers_follow_the_backend_rule(wrapper):
     assert inspect.signature(wrapper).parameters["interpret"].default is None
     assert resolve_interpret(None) == (jax.default_backend() != "tpu")
     assert resolve_interpret(False) is False and resolve_interpret(True) is True
+
+
+def test_a_named_platform_decides_interpret_mode_and_operand_dtype():
+    # a program compiled for a described TPU runs its kernels compiled and
+    # feeds them the operands XLA's default-precision matmuls round to; a
+    # raised precision keeps them float32
+    assert resolve_interpret(None, platform="tpu") is False
+    assert resolve_interpret(None, platform="cpu") is True
+    assert resolve_interpret(True, platform="tpu") is True
+    assert matmul_operand_dtype("tpu") == jnp.bfloat16
+    assert matmul_operand_dtype("cpu") == jnp.float32
+    for raised in ("high", "highest", "float32"):
+        with jax.default_matmul_precision(raised):
+            assert matmul_operand_dtype("tpu") == jnp.float32
+    with jax.default_matmul_precision("bfloat16"):
+        assert matmul_operand_dtype("tpu") == jnp.bfloat16
